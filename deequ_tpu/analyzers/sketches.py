@@ -126,10 +126,12 @@ class ApproxCountDistinct(StandardScanShareableAnalyzer[ApproxCountDistinctState
                 regs_full = np.zeros(M, dtype=np.int32)
                 np.maximum.at(regs_full, idx, pw)
                 perm = np.argsort(idx, kind="stable")
-                aux["hll_regs_full"] = regs_full
                 aux["hll_perm"] = perm
                 aux["hll_pw_sorted"] = pw[perm]
                 aux["hll_starts"] = np.searchsorted(idx[perm], np.arange(M))
+                # published last: a concurrent fold of the same column
+                # that finds it finds the sorted view too
+                aux["hll_regs_full"] = regs_full
             if self.where is None and ctx.run_token is not None:
                 # cross-batch skip: within one pass, registers are a MAX
                 # fold over batch partials, so an entry only needs to reach

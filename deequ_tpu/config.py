@@ -15,19 +15,37 @@ import jax
 if not os.environ.get("DEEQU_TPU_NO_X64"):
     jax.config.update("jax_enable_x64", True)
 
+#: directory for what the program caches at run time, fixed inside the
+#: checkout (gitignored): a cache whose path moves never hits
+CACHE_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".cache"
+)
+
+#: env var: "1" turns the persistent XLA compilation cache off
+NO_COMPILE_CACHE_ENV = "DEEQU_TPU_NO_COMPILE_CACHE"
+
+
+def compile_cache_dir():
+    """Where compiled programs persist: ``JAX_COMPILATION_CACHE_DIR`` when
+    set (jax reads it itself), else ``<checkout>/.cache/xla``; None when
+    the cache is off."""
+    if os.environ.get(NO_COMPILE_CACHE_ENV):
+        return None
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        CACHE_ROOT, "xla"
+    )
+
+
 # persistent XLA compilation cache: fused analyzer programs are large (tens
 # of seconds to compile) and identical across processes/runs
-if not os.environ.get("DEEQU_TPU_NO_COMPILE_CACHE"):
-    _cache_dir = os.environ.get(
-        "DEEQU_TPU_COMPILE_CACHE", os.path.expanduser("~/.cache/deequ_tpu_xla")
-    )
-    try:
-        os.makedirs(_cache_dir, exist_ok=True)
+_cache_dir = compile_cache_dir()
+if _cache_dir is None:
+    jax.config.update("jax_enable_compilation_cache", False)
+else:
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:  # noqa: BLE001 - cache is best-effort
-        pass
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 import jax.numpy as jnp  # noqa: E402  (after x64 setup)
 
@@ -422,8 +440,8 @@ from .service.catalog import (  # noqa: E402,F401
 #   routing behavior (the escape hatch; pinned by tests/test_tuning.py).
 #   Default on.
 # - DEEQU_TPU_TUNING_PROFILE_DIR: directory holding the checksummed
-#   per-substrate calibration profiles (default: a deequ_tpu_tuning
-#   directory beside the DEEQU_TPU_COMPILE_CACHE XLA cache). One file
+#   per-substrate calibration profiles (default: <checkout>/.cache/
+#   tuning, beside the in-checkout XLA compile cache). One file
 #   per substrate fingerprint; corrupt or stale files are quarantined
 #   into .quarantine/ and the service boots on static defaults.
 # - DEEQU_TPU_TUNING_SHADOW_FRACTION: fraction of eligible folds the

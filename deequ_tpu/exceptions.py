@@ -47,7 +47,7 @@ class EmptyStateException(MetricCalculationRuntimeException):
 
 class DeviceFailureException(MetricCalculationRuntimeException):
     """The accelerator tier failed for INFRASTRUCTURE reasons (XLA runtime
-    error, lost device, relay/tunnel fault) rather than anything about the
+    error, lost device, host-link fault) rather than anything about the
     data or the analyzer. The reliability layer treats this class as
     tier-recoverable: the same battery re-runs on the host ingest tier,
     which shares no device state with the failed pass."""

@@ -79,27 +79,11 @@ def _local_view(tree):
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """`shard_map` across jax versions: the top-level API where present,
-    else `jax.experimental.shard_map` (0.4.x). Replication checking is
-    disabled either way — the merge programs intentionally return
-    per-device values from replicated inputs — but the FLAG NAME also
-    changed (`check_rep` -> `check_vma`) on a different release than the
-    top-level promotion, so each flag spelling is tried rather than keyed
-    off the API location."""
-    if hasattr(jax, "shard_map"):
-        api = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as api
-    try:
-        return api(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:
-        return api(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+    """`jax.shard_map` without replication checking: the merge programs
+    intentionally return per-device values from replicated inputs."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def make_mesh(num_devices: Optional[int] = None, devices=None) -> Mesh:
@@ -195,9 +179,9 @@ def sharded_ingest_fold(
     program = _SHARDED_INGEST_CACHE.get(key)
     if program is None:
         def spec_of(tree):
-            # jnp.asarray reads ndim without a D2H transfer of device leaves
+            # np.ndim reads the rank from metadata: no transfer either way
             return jax.tree_util.tree_map(
-                lambda x: P(ROW_AXIS, *([None] * (jnp.asarray(x).ndim - 1))), tree
+                lambda x: P(ROW_AXIS, *([None] * (np.ndim(x) - 1))), tree
             )
 
         from ..runners.engine import make_flagged_ingest_body
@@ -248,15 +232,15 @@ def sharded_ingest_fold(
 
 def stack_identity_states(analyzers: Sequence[Any], n_dev: int):
     """n_dev copies of each analyzer's identity state, leading dim n_dev —
-    the initial per-device states for :func:`sharded_ingest_fold`."""
+    the initial per-device states for :func:`sharded_ingest_fold`. Host
+    arrays: the fold's program places each row on its own device (built on
+    the default device they would be copied there chip to chip)."""
     out = []
     for a in analyzers:
         ident = a.init_state()
         out.append(
             jax.tree_util.tree_map(
-                lambda x: jnp.broadcast_to(
-                    jnp.asarray(x)[None], (n_dev,) + jnp.asarray(x).shape
-                ),
+                lambda x: np.repeat(np.asarray(x)[None], n_dev, axis=0),
                 ident,
             )
         )
@@ -306,8 +290,13 @@ def collective_merge_states(analyzers: Sequence[Any], mesh: Mesh, per_shard_stat
             ident = a.init_state()
 
             def pad_leaf(x, i):
-                tile = jnp.broadcast_to(jnp.asarray(i)[None], (pad,) + jnp.asarray(i).shape)
-                return jnp.concatenate([jnp.asarray(x), tile.astype(jnp.asarray(x).dtype)], axis=0)
+                # identity rows from the host: built on the default device
+                # they would be copied chip to chip into the mesh
+                i = np.asarray(i)
+                tile = np.broadcast_to(i[None], (pad,) + i.shape).astype(x.dtype)
+                if isinstance(x, jax.Array):
+                    return _concat_rows(x, tile)
+                return np.concatenate([np.asarray(x), tile], axis=0)
 
             tree = jax.tree_util.tree_map(pad_leaf, tree, ident)
         padded.append(tree)
@@ -334,7 +323,7 @@ def collective_merge_states(analyzers: Sequence[Any], mesh: Mesh, per_shard_stat
     program = _COLLECTIVE_MERGE_CACHE.get(cache_key)
     if program is None:
         shard_spec = jax.tree_util.tree_map(
-            lambda x: P(ROW_AXIS, *([None] * (jnp.asarray(x).ndim - 1))), padded
+            lambda x: P(ROW_AXIS, *([None] * (np.ndim(x) - 1))), padded
         )
         pow2 = (n_dev & (n_dev - 1)) == 0
 
@@ -386,9 +375,19 @@ def collective_merge_states(analyzers: Sequence[Any], mesh: Mesh, per_shard_stat
     # every device holds the identical full merge; take device 0's copy
     # (each PROCESS reads its own addressable replica on a DCN mesh)
     merged = _local_view(merged)
-    return tuple(
-        jax.tree_util.tree_map(lambda x: x[0], tree) for tree in merged
-    )
+    return _first_rows(merged)
+
+
+@jax.jit
+def _concat_rows(x, tile):
+    return jnp.concatenate([x, tile], axis=0)
+
+
+@jax.jit
+def _first_rows(trees):
+    """Row 0 of every leaf, in one program: eager indexing would stage its
+    index constant on the default device, outside a sub-mesh."""
+    return jax.tree_util.tree_map(lambda x: x[0], trees)
 
 
 # elastic fault tolerance rides on the primitives above; imported LAST so

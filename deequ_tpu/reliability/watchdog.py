@@ -2,7 +2,7 @@
 
 The PR-2 reliability layer reacts to RAISED exceptions — isolation,
 failover, retry all begin when something throws. A pass that HANGS (a
-wedged device tunnel, a collective waiting on a peer that died, a kernel
+wedged device link, a collective waiting on a peer that died, a kernel
 spinning on a poisoned shape) defeats all of it: the worker blocks
 forever, the battery never degrades, the scheduler queue backs up behind
 a job that will never finish. This module closes that gap with the
@@ -11,7 +11,10 @@ hang-detection analog of a thrown fault:
 - every engine pass runs under a DEADLINE derived from the measured
   per-ROW rate of previous passes on the same tier (a generous
   multiple, so normal variance never trips; per-row so micro-batch and
-  full-batch passes share one honest rate), overridable with
+  full-batch passes share one honest rate), extended by the longest
+  compile seen so far for each program the pass has never run (a rate
+  learned from warm passes says nothing about a cold TPU compile),
+  overridable with
   ``DEEQU_TPU_SCAN_DEADLINE_S`` (<= 0 disables);
 - a pass exceeding its deadline is cancelled — the caller gets a typed
   :class:`~deequ_tpu.exceptions.ScanStallError`, which classifies as a
@@ -59,9 +62,39 @@ SCAN_DEADLINE_ENV = "DEEQU_TPU_SCAN_DEADLINE_S"
 #: spurious failover) is far higher than a few extra seconds of waiting
 DEADLINE_RATE_MULTIPLE = 10.0
 
-#: floor on any derived deadline: compile time, feed-link warmup and probe
-#: costs all amortize into the first batches, so short passes get slack
+#: floor on any derived deadline: feed warmup and probe costs amortize
+#: into the first batches, so short passes get slack
 DEADLINE_FLOOR_S = 30.0
+
+#: longest XLA backend compile this process has seen (seconds); a pass
+#: about to compile gets this much extra per program it has never run
+_LONGEST_COMPILE_S = 0.0
+_COMPILE_LISTENER = False
+
+
+def _note_compile(event: str, duration: float, **_) -> None:
+    global _LONGEST_COMPILE_S
+    if event == "/jax/core/compile/backend_compile_duration":
+        _LONGEST_COMPILE_S = max(_LONGEST_COMPILE_S, float(duration))
+
+
+def watch_compiles() -> None:
+    """Start recording compile durations (idempotent)."""
+    global _COMPILE_LISTENER
+    if not _COMPILE_LISTENER:
+        import jax
+
+        _COMPILE_LISTENER = True
+        jax.monitoring.register_event_duration_secs_listener(_note_compile)
+
+
+def compile_allowance_s(cold_programs: int) -> float:
+    """Deadline extension for a pass that will compile ``cold_programs``
+    programs: a rate learned from warm passes says nothing about compile
+    time, and a first TPU compile takes tens of seconds to minutes."""
+    if cold_programs <= 0:
+        return 0.0
+    return cold_programs * max(DEADLINE_FLOOR_S, _LONGEST_COMPILE_S)
 
 
 class RateTracker:
@@ -113,9 +146,13 @@ def rate_tracker() -> RateTracker:
 _ENV_WARNED = False
 
 
-def scan_deadline_s(n_rows: int, tier: str) -> Optional[float]:
-    """The deadline for a pass over ``n_rows`` on ``tier``, or None
-    (watchdog disabled: no override and no measured rate yet)."""
+def scan_deadline_s(
+    n_rows: int, tier: str, cold_programs: int = 0
+) -> Optional[float]:
+    """The deadline for a pass over ``n_rows`` on ``tier`` that will
+    compile ``cold_programs`` programs, or None (watchdog disabled: no
+    override and no measured rate yet)."""
+    watch_compiles()
     env = os.environ.get(SCAN_DEADLINE_ENV)
     if env is not None:
         try:
@@ -143,7 +180,7 @@ def scan_deadline_s(n_rows: int, tier: str) -> Optional[float]:
     return max(
         DEADLINE_FLOOR_S,
         DEADLINE_RATE_MULTIPLE * per_row * max(int(n_rows), 1),
-    )
+    ) + compile_allowance_s(cold_programs)
 
 
 def run_with_deadline(

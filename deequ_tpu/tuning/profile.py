@@ -41,18 +41,15 @@ PROFILE_VERSION = 1
 
 
 def profile_dir() -> str:
-    """Profile directory: ``DEEQU_TPU_TUNING_PROFILE_DIR`` or a
-    ``deequ_tpu_tuning`` directory beside the XLA compile cache."""
+    """Profile directory: ``DEEQU_TPU_TUNING_PROFILE_DIR`` or
+    ``<checkout>/.cache/tuning``."""
+    from ..config import CACHE_ROOT
     from ..utils import env_str
 
     configured = env_str(_knobs.TUNING_PROFILE_DIR_ENV, "")
     if configured:
         return os.path.expanduser(configured)
-    cache = env_str(
-        "DEEQU_TPU_COMPILE_CACHE", os.path.expanduser("~/.cache/deequ_tpu_xla")
-    )
-    return os.path.join(os.path.dirname(os.path.expanduser(cache)) or ".",
-                        "deequ_tpu_tuning")
+    return os.path.join(CACHE_ROOT, "tuning")
 
 
 def substrate_key() -> Dict[str, Any]:

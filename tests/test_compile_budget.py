@@ -2,7 +2,7 @@
 
 The device tier used to compile ONE monolithic PackedScanProgram keyed on
 the full analyzer tuple: a 50-column battery was one giant XLA compile
-(1140.6s staging vs 1.98s warm on the bench box — 575x, BENCH_r05) that no
+(1140.6s staging vs 1.98s warm in the July chip round — 575x) that no
 other battery could reuse. The bundled design partitions a battery into
 (analyzer-class, state-shape) signature bundles and compiles one SMALL
 program per bundle signature, shared across columns, batteries and runs.

@@ -240,3 +240,35 @@ class TestStallDrills:
                 counters["deequ_service_scan_stalls_total"]["tenant=t"] == 1.0
             )
             assert ("hsig",) not in svc.router._device_suspect
+
+
+class TestCompileAllowance:
+    """A pass about to compile gets the longest compile seen so far per
+    program it has never run: a rate learned on warm passes says nothing
+    about a cold TPU compile (tens of seconds)."""
+
+    def test_cold_programs_extend_the_derived_deadline(self, monkeypatch):
+        from deequ_tpu.reliability import watchdog
+
+        monkeypatch.setattr(watchdog, "_LONGEST_COMPILE_S", 45.0)
+        rate_tracker().observe("device", rows=1000, seconds=1.0)
+        assert scan_deadline_s(2000, "device") == 30.0
+        assert scan_deadline_s(2000, "device", cold_programs=2) == 30.0 + 90.0
+
+    def test_allowance_never_below_the_floor(self, monkeypatch):
+        from deequ_tpu.reliability import watchdog
+
+        monkeypatch.setattr(watchdog, "_LONGEST_COMPILE_S", 0.5)
+        assert watchdog.compile_allowance_s(0) == 0.0
+        assert watchdog.compile_allowance_s(3) == 3 * watchdog.DEADLINE_FLOOR_S
+
+    def test_compiles_are_recorded(self, monkeypatch):
+        import jax
+        import jax.numpy as jnp
+
+        from deequ_tpu.reliability import watchdog
+
+        monkeypatch.setattr(watchdog, "_LONGEST_COMPILE_S", 0.0)
+        watchdog.watch_compiles()
+        jax.jit(lambda x: jnp.sin(x) * 3.0 + 1.25)(jnp.arange(7.0))
+        assert watchdog._LONGEST_COMPILE_S > 0.0

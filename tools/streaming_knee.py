@@ -119,6 +119,25 @@ def run_knee_point(
     }
 
 
+def _run_child(args: List[str]) -> Dict:
+    """``python -m tools.streaming_knee <args>`` in a child process; its
+    last stdout line, JSON. Every JAX-touching step of the grid runs in a
+    child, so this process never holds the chip a child needs."""
+    import subprocess
+
+    proc = subprocess.run(
+        [sys.executable, "-m", "tools.streaming_knee", *args],
+        capture_output=True, text=True, timeout=900,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    if proc.returncode != 0:
+        raise RuntimeError(
+            f"streaming_knee child {args[:2]} rc={proc.returncode}: "
+            f"{proc.stderr[-400:]}"
+        )
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
 def _subprocess_point(
     sessions: int, rows: int, coalesce: bool, repeats: int,
     batches: int, workers: int, queue_depth: int,
@@ -128,26 +147,13 @@ def _subprocess_point(
     previous points left in the interpreter — measured drift was tens of
     percent by the fourth in-process point. Same isolation discipline as
     the bench's grouping/mesh subprocess points."""
-    import subprocess
-
     runs = []
     for _ in range(max(1, repeats)):
-        argv = [
-            sys.executable, "-m", "tools.streaming_knee", "--point",
-            str(sessions), str(rows), "1" if coalesce else "0", "1",
-            "--batches", str(batches), "--workers", str(workers),
+        runs.append(_run_child([
+            "--point", str(sessions), str(rows), "1" if coalesce else "0",
+            "1", "--batches", str(batches), "--workers", str(workers),
             "--queue-depth", str(queue_depth),
-        ]
-        proc = subprocess.run(
-            argv, capture_output=True, text=True, timeout=900,
-            cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        )
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"knee point subprocess rc={proc.returncode}: "
-                f"{proc.stderr[-400:]}"
-            )
-        runs.append(json.loads(proc.stdout.strip().splitlines()[-1]))
+        ]))
     runs.sort(key=lambda r: r["sessions_per_s"])
     point = dict(runs[len(runs) // 2])  # fully-isolated median
     # spread across the isolated repeats (see run_knee_point: the bimodal
@@ -195,7 +201,7 @@ def run_grid(
                 "shed": serial["shed"] + coalesced["shed"],
                 "ok": serial["ok"] and coalesced["ok"],
             })
-    parity = _parity_probe(rows=4096)
+    parity = _run_child(["--parity", "4096"])
     # the acceptance cell: 1000 sessions x 4096-row micro-batches
     headline = next(
         (p for p in points if p["sessions"] == max(session_counts)
@@ -225,7 +231,13 @@ def main(argv=None) -> int:
     parser.add_argument("--point", nargs=4, metavar=("S", "R", "C", "N"),
                         help="internal: run ONE point (sessions rows "
                              "coalesce repeats) and print its JSON")
+    parser.add_argument("--parity", type=int, metavar="ROWS",
+                        help="internal: run the parity probe and print "
+                             "its JSON")
     args = parser.parse_args(argv)
+    if args.parity:
+        print(json.dumps(_parity_probe(rows=args.parity)), flush=True)
+        return 0
     if args.point:
         sessions, rows, coalesce, repeats = (int(x) for x in args.point)
         point = run_knee_point(
