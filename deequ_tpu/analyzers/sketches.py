@@ -276,6 +276,7 @@ class ApproxCountDistinct(StandardScanShareableAnalyzer[ApproxCountDistinctState
         np.maximum.at(regs, pairs[0][mask], pairs[1][mask])
         return ApproxCountDistinctState(regs)
 
+    @jax.named_scope("hll_update")
     def update(self, state, features):
         from ..ops.hll import M
 

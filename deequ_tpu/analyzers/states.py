@@ -12,6 +12,7 @@ from __future__ import annotations
 from typing import Any
 
 import flax.struct
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -109,6 +110,7 @@ class FrequencyTableState:
             jnp.zeros((), dtype=jnp.int64),
         )
 
+    @jax.named_scope("freq_compact")
     def compacted(self) -> "FrequencyTableState":
         """Fold the raw buffer into the sorted table (buffer becomes
         empty); traced — both the in-pass overflow branch and ``merge``
@@ -136,6 +138,7 @@ class FrequencyTableState:
             self.num_rows,
         )
 
+    @jax.named_scope("freq_append")
     def append_keys(
         self,
         keys: jnp.ndarray,
@@ -158,8 +161,6 @@ class FrequencyTableState:
         conditional-compaction trace remains for runs whose rows exceed the
         buffer; its sort cost amortizes over ``buffer_entries / batch``
         batches."""
-        import jax
-
         batch = keys.shape[0]
         cap = self.buf.shape[0]
         if batch > cap:
@@ -187,6 +188,7 @@ class FrequencyTableState:
             num_rows=appended.num_rows + n_rows,
         )
 
+    @jax.named_scope("freq_merge")
     def merge(self, other: "FrequencyTableState") -> "FrequencyTableState":
         from ..ops import freq_compact
         from ..ops.hashing import FREQ_KEY_SENTINEL
@@ -435,6 +437,7 @@ class ApproxCountDistinctState:
 
         return ApproxCountDistinctState(jnp.zeros(M, dtype=jnp.int32))
 
+    @jax.named_scope("hll_merge")
     def merge(self, other: "ApproxCountDistinctState") -> "ApproxCountDistinctState":
         return ApproxCountDistinctState(jnp.maximum(self.registers, other.registers))
 

@@ -233,8 +233,6 @@ def slim_fetch_enabled() -> bool:
 #   size (default: all cores; 0/unset = default; warn-and-fallback).
 # - DEEQU_TPU_DEVICE_FEATURE_CACHE: HBM budget in GB for the
 #   device-resident feature cache; unset/"0" disables (warn-and-fallback).
-# - DEEQU_TPU_PROFILE_DIR: directory receiving a jax.profiler trace of
-#   every pass; unset = profiling off.
 # - DEEQU_TPU_NO_NATIVE: "1" disables the native host kernels entirely
 #   (pure-Python fallbacks); read at deequ_tpu.native.lib import.
 # - DEEQU_TPU_ADAPTIVE_DICT_ENCODE: "0" disables ingest-time adaptive

@@ -415,17 +415,22 @@ def extract(header: Optional[str]) -> Any:
     return TraceContext(trace_id, span_id, sampled=True)
 
 
-def record_phase(phase: str, start_ns: int, end_ns: int) -> None:
+def record_phase(
+    phase: str, start_ns: int, end_ns: int,
+    attrs: Optional[Dict[str, Any]] = None,
+) -> None:
     """Publish one already-measured phase interval as a finished child span
     of the current context. This is the hot-path entry the engine's
     ``RunMonitor.timed`` phase timers call: the timestamps are the timer's
     own, so ``phase_seconds`` numbers and span durations can never
-    disagree, and an untraced thread pays a single attribute read."""
+    disagree, and an untraced thread pays a single attribute read. The
+    same timers open a ``jax.profiler.TraceAnnotation`` named
+    ``deequ/<phase>``, which puts every phase in a device trace too."""
     parent = _top()
     if not isinstance(parent, Span):
         return
     sp = Span(
         phase, "phase", trace_id=parent.trace_id, span_id=_next_span_id(),
-        parent_id=parent.span_id, start_ns=start_ns,
+        parent_id=parent.span_id, start_ns=start_ns, attrs=attrs,
     )
     sp.finish(end_ns=end_ns)

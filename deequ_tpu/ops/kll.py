@@ -230,6 +230,7 @@ def _compact_cascade_from(
     return items, sizes, parity
 
 
+@jax.named_scope("kll_update")
 def kll_update(state: KLLSketchState, values: jnp.ndarray, valid: jnp.ndarray) -> KLLSketchState:
     """Fold one batch (fixed shape, masked) into the sketch. Pure jax; safe
     under jit/shard_map. NaNs are excluded from the sketch."""
@@ -324,6 +325,7 @@ def kll_ingest_sampled(
     )
 
 
+@jax.named_scope("kll_merge")
 def kll_merge(a: KLLSketchState, b: KLLSketchState) -> KLLSketchState:
     """Semigroup sum: concatenate per-level buffers and re-compact
     (reference `QuantileNonSample.merge`, `analyzers/QuantileNonSample.scala:

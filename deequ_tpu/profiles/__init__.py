@@ -155,18 +155,15 @@ class ColumnProfiler:
         sharding=None,
         placement: Optional[str] = None,
     ) -> ColumnProfiles:
-        """(reference `ColumnProfiler.profile`, `ColumnProfiler.scala:91-208`)."""
-        predefined_types = dict(predefined_types or {})
-        schema = data.schema
-        if restrict_to_columns is not None:
-            for name in restrict_to_columns:
-                if name not in schema:
-                    raise ValueError(f"Unable to find column {name}")
-        relevant = [
-            c.name
-            for c in schema.columns
-            if restrict_to_columns is None or c.name in restrict_to_columns
-        ]
+        """(reference `ColumnProfiler.profile`, `ColumnProfiler.scala:91-208`).
+
+        The whole profile is one ``profile_run`` span; the host work around
+        its passes times under the ``plan`` phase of ``monitor`` (of a
+        local monitor when none is given)."""
+        from ..observability import trace as _trace
+        from ..runners.engine import RunMonitor
+
+        phases = monitor if monitor is not None else RunMonitor()
         run_kwargs = dict(
             metrics_repository=metrics_repository,
             reuse_existing_results_for_key=reuse_existing_results_using_key,
@@ -177,146 +174,196 @@ class ColumnProfiler:
             sharding=sharding,
             placement=placement,
         )
+        with _trace.span("profile_run", kind="analysis"):
+            with phases.timed("plan"):
+                predefined_types = dict(predefined_types or {})
+                schema = data.schema
+                if restrict_to_columns is not None:
+                    for name in restrict_to_columns:
+                        if name not in schema:
+                            raise ValueError(f"Unable to find column {name}")
+                relevant = [
+                    c.name
+                    for c in schema.columns
+                    if restrict_to_columns is None or c.name in restrict_to_columns
+                ]
+                first_pass = _first_pass_analyzers(
+                    data, relevant, predefined_types, kll_parameters,
+                    low_cardinality_histogram_threshold,
+                )
+            # ---- PASS 1: generic statistics (reference `:122-139`) PLUS
+            # the numeric statistics of schema-typed numeric columns and the
+            # histograms of small-dictionary columns
+            if print_status_updates:
+                print("### PROFILING: Computing generic column statistics in pass (1/2)...")
+            first_results = AnalysisRunner.do_analysis_run(data, first_pass, **run_kwargs)
 
-        # ---- PASS 1: generic statistics (reference `:122-139`) PLUS the
-        # numeric statistics of columns the SCHEMA already types as numeric:
-        # those don't depend on pass-1 type inference, so they share the
-        # first scan (the reference always defers them to pass 2,
-        # `ColumnProfiler.scala:153-171` — for an all-native-typed table this
-        # build profiles in ONE data pass plus the low-card histogram scan)
-        if print_status_updates:
-            print("### PROFILING: Computing generic column statistics in pass (1/2)...")
-        first_pass: List[Any] = [Size()]
-        for name in relevant:
-            first_pass.append(Completeness(name))
-            first_pass.append(ApproxCountDistinct(name))
-            if schema[name].kind == ColumnKind.STRING and name not in predefined_types:
-                first_pass.append(DataType(name))
-            elif schema[name].kind.is_numeric and predefined_types.get(
-                name, INTEGRAL
-            ) in (INTEGRAL, FRACTIONAL):
-                # skipped when the user predefines the column as non-numeric
-                first_pass += _numeric_analyzers(name, kll_parameters)
-        # histograms of DICTIONARY-ENCODED columns whose dictionary is
-        # already <= the cardinality threshold join pass 1 (distinct <=
-        # dictionary size, so eligibility cannot be decided otherwise after
-        # the scan); the reference always needs its third pass for these
-        # (`ColumnProfiler.scala:181-205`). Columns the HLL estimate later
-        # DISQUALIFIES (estimate error can exceed the threshold even when
-        # the true cardinality is under it) are dropped below, preserving
-        # reference semantics. Histograms count ORIGINAL values, so running
-        # them before the numeric-string cast is exactly right.
-        hist_pass1 = {
-            name
-            for name in relevant
+            with phases.timed("plan"):
+                generic = _extract_generic_statistics(
+                    relevant, schema, first_results, predefined_types
+                )
+                casted, second_pass, extra_hist_pass, histogram_columns = (
+                    _second_pass_plan(
+                        data, relevant, schema, generic, kll_parameters,
+                        low_cardinality_histogram_threshold,
+                        {a.column for a in first_pass if isinstance(a, Histogram)},
+                    )
+                )
+
+            # ---- PASS 2: numeric statistics on the casted view + exact
+            # histograms of low-cardinality columns, ONE shared scan
+            # (reference needs separate passes 2 and 3, `:153-205`) ----
+            if print_status_updates:
+                print(
+                    "### PROFILING: Computing numeric statistics + low-cardinality "
+                    "histograms in pass (2/2)..."
+                )
+            second_results = None
+            third_results = None
             if (
-                (size := data.dictionary_size(name)) is not None
-                and size <= low_cardinality_histogram_threshold
-            )
-        }
-        first_pass += [Histogram(name) for name in sorted(hist_pass1)]
-        first_results = AnalysisRunner.do_analysis_run(data, first_pass, **run_kwargs)
+                second_pass
+                and extra_hist_pass
+                and run_kwargs.get("save_or_append_results_with_key") is None
+            ):
+                # the two pass-2 scans are INDEPENDENT (numeric stats over the
+                # casted view vs raw-value histograms of casted columns), so
+                # they overlap: one thread's state fetch rides the feed link
+                # while the other's batches stream through the async device
+                # queue — the 2-pass overlap the slim-fetch redesign calls for.
+                # (With a repository save key the runs stay sequential: the
+                # append path is read-modify-write on the shared repository.)
+                # The pool thread joins this profile's trace.
+                from concurrent.futures import ThreadPoolExecutor
 
-        generic = _extract_generic_statistics(
-            relevant, schema, first_results, predefined_types
-        )
+                ctx = _trace.capture()
 
-        # ---- PASS 2: numeric statistics on the casted view + exact
-        # histograms of low-cardinality columns, ONE shared scan
-        # (reference needs separate passes 2 and 3, `:153-205`) ----
-        if print_status_updates:
-            print(
-                "### PROFILING: Computing numeric statistics + low-cardinality "
-                "histograms in pass (2/2)..."
-            )
-        casted, casted_names = _cast_numeric_string_columns(relevant, data, generic)
-        second_pass: List[Any] = []
-        for name in relevant:
-            if generic.type_of(name) in (INTEGRAL, FRACTIONAL) and not schema[
-                name
-            ].kind.is_numeric:
-                # only inference-detected (casted string) columns remain;
-                # schema-typed numerics already ran in pass 1
-                second_pass += _numeric_analyzers(name, kll_parameters)
-        histogram_columns = _find_target_columns_for_histograms(
-            schema, generic, low_cardinality_histogram_threshold
-        )
-        # histograms must count ORIGINAL values (reference pass 3 reads the
-        # raw data, `getHistogramsForThirdPass`): share pass 2 only for
-        # columns the cast did not touch, else run them in an extra pass;
-        # columns already histogrammed in pass 1 are done either way
-        remaining_hist = [c for c in histogram_columns if c not in hist_pass1]
-        shared_hist = [c for c in remaining_hist if c not in casted_names]
-        extra_hist = [c for c in remaining_hist if c in casted_names]
-        # pass-1 estimates prove these columns low-cardinality, so encode
-        # them now (floats/ints included): their histograms then ride the
-        # device frequency scan instead of a per-batch host group-by. The
-        # encoded view memoizes on the source dataset so repeated profiles
-        # reuse ONE arrow table (keeping the device feature cache hot).
-        encodable = tuple(
-            c for c in shared_hist if casted.dictionary_size(c) is None
-        )
-        if encodable:
-            ekey = ("__profile_encoded__", tuple(sorted(casted_names)), encodable)
-            encoded = data.derived_cache.get(ekey)
-            if encoded is None:
-                encoded = casted.with_columns_dictionary_encoded(encodable)
-                data.derived_cache[ekey] = encoded
-            casted = encoded
-        second_pass += [Histogram(name) for name in shared_hist]
-        second_results = None
-        third_results = None
-        extra_hist_pass = [Histogram(name) for name in extra_hist]
+                def second_scan():
+                    with _trace.attach(ctx):
+                        return AnalysisRunner.do_analysis_run(
+                            casted, second_pass, **run_kwargs
+                        )
+
+                with ThreadPoolExecutor(
+                    max_workers=1, thread_name_prefix="deequ-profile-pass"
+                ) as pool:
+                    second_future = pool.submit(second_scan)
+                    third_results = AnalysisRunner.do_analysis_run(
+                        data, extra_hist_pass, **run_kwargs
+                    )
+                    second_results = second_future.result()
+            else:
+                if second_pass:
+                    second_results = AnalysisRunner.do_analysis_run(
+                        casted, second_pass, **run_kwargs
+                    )
+                if extra_hist_pass:
+                    third_results = AnalysisRunner.do_analysis_run(
+                        data, extra_hist_pass, **run_kwargs
+                    )
+
+            with phases.timed("plan"):
+                numeric_stats = _extract_numeric_statistics(first_results, second_results)
+                histograms: Dict[str, Distribution] = {}
+                eligible_hist = set(histogram_columns)
+                for results in (first_results, second_results, third_results):
+                    if results is None:
+                        continue
+                    for analyzer, metric in results.metric_map.items():
+                        if (
+                            isinstance(analyzer, Histogram)
+                            and metric.value.is_success
+                            and analyzer.column in eligible_hist
+                        ):
+                            histograms[analyzer.column] = metric.value.get()
+                return _create_profiles(relevant, generic, numeric_stats, histograms)
+
+
+def _first_pass_analyzers(
+    data, relevant, predefined_types, kll_parameters, threshold
+) -> List[Any]:
+    """Pass 1's battery: generic statistics (reference `:122-139`) PLUS the
+    numeric statistics of columns the SCHEMA already types as numeric:
+    those don't depend on pass-1 type inference, so they share the first
+    scan (the reference always defers them to pass 2,
+    `ColumnProfiler.scala:153-171` — for an all-native-typed table this
+    build profiles in ONE data pass plus the low-card histogram scan).
+
+    Histograms of DICTIONARY-ENCODED columns whose dictionary is already <=
+    the cardinality threshold join pass 1 (distinct <= dictionary size, so
+    eligibility cannot be decided otherwise after the scan); the reference
+    always needs its third pass for these (`ColumnProfiler.scala:181-205`).
+    Columns the HLL estimate later DISQUALIFIES (estimate error can exceed
+    the threshold even when the true cardinality is under it) are dropped
+    after the scan, preserving reference semantics. Histograms count
+    ORIGINAL values, so running them before the numeric-string cast is
+    exactly right."""
+    schema = data.schema
+    first_pass: List[Any] = [Size()]
+    for name in relevant:
+        first_pass.append(Completeness(name))
+        first_pass.append(ApproxCountDistinct(name))
+        if schema[name].kind == ColumnKind.STRING and name not in predefined_types:
+            first_pass.append(DataType(name))
+        elif schema[name].kind.is_numeric and predefined_types.get(
+            name, INTEGRAL
+        ) in (INTEGRAL, FRACTIONAL):
+            # skipped when the user predefines the column as non-numeric
+            first_pass += _numeric_analyzers(name, kll_parameters)
+    hist_pass1 = {
+        name
+        for name in relevant
         if (
-            second_pass
-            and extra_hist_pass
-            and run_kwargs.get("save_or_append_results_with_key") is None
-        ):
-            # the two pass-2 scans are INDEPENDENT (numeric stats over the
-            # casted view vs raw-value histograms of casted columns), so
-            # they overlap: one thread's state fetch rides the feed link
-            # while the other's batches stream through the async device
-            # queue — the 2-pass overlap the slim-fetch redesign calls for.
-            # (With a repository save key the runs stay sequential: the
-            # append path is read-modify-write on the shared repository.)
-            from concurrent.futures import ThreadPoolExecutor
+            (size := data.dictionary_size(name)) is not None
+            and size <= threshold
+        )
+    }
+    return first_pass + [Histogram(name) for name in sorted(hist_pass1)]
 
-            with ThreadPoolExecutor(
-                max_workers=1, thread_name_prefix="deequ-profile-pass"
-            ) as pool:
-                second_future = pool.submit(
-                    AnalysisRunner.do_analysis_run, casted, second_pass,
-                    **run_kwargs,
-                )
-                third_results = AnalysisRunner.do_analysis_run(
-                    data, extra_hist_pass, **run_kwargs
-                )
-                second_results = second_future.result()
-        else:
-            if second_pass:
-                second_results = AnalysisRunner.do_analysis_run(
-                    casted, second_pass, **run_kwargs
-                )
-            if extra_hist_pass:
-                third_results = AnalysisRunner.do_analysis_run(
-                    data, extra_hist_pass, **run_kwargs
-                )
 
-        numeric_stats = _extract_numeric_statistics(first_results, second_results)
-        histograms: Dict[str, Distribution] = {}
-        eligible_hist = set(histogram_columns)
-        for results in (first_results, second_results, third_results):
-            if results is None:
-                continue
-            for analyzer, metric in results.metric_map.items():
-                if (
-                    isinstance(analyzer, Histogram)
-                    and metric.value.is_success
-                    and analyzer.column in eligible_hist
-                ):
-                    histograms[analyzer.column] = metric.value.get()
-
-        return _create_profiles(relevant, generic, numeric_stats, histograms)
+def _second_pass_plan(
+    data, relevant, schema, generic, kll_parameters, threshold, hist_pass1
+):
+    """Pass 2's inputs from pass 1's results: the casted view, its battery
+    (numeric statistics of inference-detected columns, histograms the cast
+    left alone), the extra pass's histograms of casted columns, and every
+    column eligible for a histogram."""
+    casted, casted_names = _cast_numeric_string_columns(relevant, data, generic)
+    second_pass: List[Any] = []
+    for name in relevant:
+        if generic.type_of(name) in (INTEGRAL, FRACTIONAL) and not schema[
+            name
+        ].kind.is_numeric:
+            # only inference-detected (casted string) columns remain;
+            # schema-typed numerics already ran in pass 1
+            second_pass += _numeric_analyzers(name, kll_parameters)
+    histogram_columns = _find_target_columns_for_histograms(
+        schema, generic, threshold
+    )
+    # histograms must count ORIGINAL values (reference pass 3 reads the
+    # raw data, `getHistogramsForThirdPass`): share pass 2 only for
+    # columns the cast did not touch, else run them in an extra pass;
+    # columns already histogrammed in pass 1 are done either way
+    remaining_hist = [c for c in histogram_columns if c not in hist_pass1]
+    shared_hist = [c for c in remaining_hist if c not in casted_names]
+    extra_hist = [c for c in remaining_hist if c in casted_names]
+    # pass-1 estimates prove these columns low-cardinality, so encode
+    # them now (floats/ints included): their histograms then ride the
+    # device frequency scan instead of a per-batch host group-by. The
+    # encoded view memoizes on the source dataset so repeated profiles
+    # reuse ONE arrow table (keeping the device feature cache hot).
+    encodable = tuple(
+        c for c in shared_hist if casted.dictionary_size(c) is None
+    )
+    if encodable:
+        ekey = ("__profile_encoded__", tuple(sorted(casted_names)), encodable)
+        encoded = data.derived_cache.get(ekey)
+        if encoded is None:
+            encoded = casted.with_columns_dictionary_encoded(encodable)
+            data.derived_cache[ekey] = encoded
+        casted = encoded
+    second_pass += [Histogram(name) for name in shared_hist]
+    extra_hist_pass = [Histogram(name) for name in extra_hist]
+    return casted, second_pass, extra_hist_pass, histogram_columns
 
 
 @dataclass

@@ -89,241 +89,245 @@ class AnalysisRunner:
         if len(analyzers) == 0:
             return AnalyzerContext.empty()
 
-        # dedupe identical analyzers, preserving order
-        seen = set()
-        unique: List[Analyzer] = []
-        for a in analyzers:
-            if a not in seen:
-                seen.add(a)
-                unique.append(a)
+        run_monitor = monitor or RunMonitor()
+        # the host work before the pass: dedupe, repository reuse,
+        # preconditions, feature validation, dictionary probes and the
+        # frequency-table plan
+        with run_monitor.timed("plan"):
+            # dedupe identical analyzers, preserving order
+            seen = set()
+            unique: List[Analyzer] = []
+            for a in analyzers:
+                if a not in seen:
+                    seen.add(a)
+                    unique.append(a)
 
-        # reuse existing results from the repository
-        # (reference `AnalysisRunner.scala:115-134`)
-        results_loaded = AnalyzerContext.empty()
-        analyzers_to_run = unique
-        if metrics_repository is not None and reuse_existing_results_for_key is not None:
-            existing = metrics_repository.load_by_key(reuse_existing_results_for_key)
-            if existing is not None:
-                loaded = {
-                    a: m for a, m in existing.metric_map.items() if a in seen
-                }
-                results_loaded = AnalyzerContext(loaded)
-                analyzers_to_run = [a for a in unique if a not in loaded]
-            if fail_if_results_missing and analyzers_to_run:
-                raise MetricCalculationException(
-                    "Could not find all necessary results in the MetricsRepository, "
-                    f"the calculation of the metrics for these analyzers would be needed: "
-                    f"{', '.join(str(a) for a in analyzers_to_run)}"
-                )
+            # reuse existing results from the repository
+            # (reference `AnalysisRunner.scala:115-134`)
+            results_loaded = AnalyzerContext.empty()
+            analyzers_to_run = unique
+            if metrics_repository is not None and reuse_existing_results_for_key is not None:
+                existing = metrics_repository.load_by_key(reuse_existing_results_for_key)
+                if existing is not None:
+                    loaded = {
+                        a: m for a, m in existing.metric_map.items() if a in seen
+                    }
+                    results_loaded = AnalyzerContext(loaded)
+                    analyzers_to_run = [a for a in unique if a not in loaded]
+                if fail_if_results_missing and analyzers_to_run:
+                    raise MetricCalculationException(
+                        "Could not find all necessary results in the MetricsRepository, "
+                        f"the calculation of the metrics for these analyzers would be needed: "
+                        f"{', '.join(str(a) for a in analyzers_to_run)}"
+                    )
 
-        # precondition partition (reference `AnalysisRunner.scala:137-145`)
-        schema = data.schema
-        passed: List[Analyzer] = []
-        failures: Dict[Analyzer, Metric] = {}
-        for a in analyzers_to_run:
-            exc = Preconditions.find_first_failing(schema, a.preconditions())
-            if exc is None:
-                passed.append(a)
-            else:
-                failures[a] = a.to_failure_metric(exc)
-
-        # validate each analyzer's features on a synthetic 1-row batch so a
-        # bad predicate/regex fails only that analyzer, not the shared scan
-        from .features import FeatureBuilder, dry_run_batch
-
-        dry = dry_run_batch(schema)
-        validated = []
-        for a in passed:
-            if isinstance(a, ScanShareableAnalyzer):
-                try:
-                    FeatureBuilder(a.feature_specs()).build(dry)
-                except Exception as exc:  # noqa: BLE001
+            # precondition partition (reference `AnalysisRunner.scala:137-145`)
+            schema = data.schema
+            passed: List[Analyzer] = []
+            failures: Dict[Analyzer, Metric] = {}
+            for a in analyzers_to_run:
+                exc = Preconditions.find_first_failing(schema, a.preconditions())
+                if exc is None:
+                    passed.append(a)
+                else:
                     failures[a] = a.to_failure_metric(exc)
-                    continue
-            validated.append(a)
-        passed = validated
-        precondition_failures = AnalyzerContext(failures)
 
-        # split: device-fused scan / grouping sets / host accumulators
-        from ..analyzers.grouping import (
-            DeviceFrequencyScan,
-            DeviceFrequencyTableScan,
-            ScanShareableFrequencyBasedAnalyzer,
-            device_freq_enabled,
-            device_freq_max_cardinality,
-            plan_table_scan,
-            probably_low_cardinality,
-        )
+            # validate each analyzer's features on a synthetic 1-row batch so a
+            # bad predicate/regex fails only that analyzer, not the shared scan
+            from .features import FeatureBuilder, dry_run_batch
 
-        dict_card_limit = device_freq_max_cardinality()
+            dry = dry_run_batch(schema)
+            validated = []
+            for a in passed:
+                if isinstance(a, ScanShareableAnalyzer):
+                    try:
+                        FeatureBuilder(a.feature_specs()).build(dry)
+                    except Exception as exc:  # noqa: BLE001
+                        failures[a] = a.to_failure_metric(exc)
+                        continue
+                validated.append(a)
+            passed = validated
+            precondition_failures = AnalyzerContext(failures)
 
-        # host-exclusive analyzers (e.g. exact-quantile mode, whose
-        # accumulator is unbounded and has no fixed-shape device fold) opt
-        # out of the fused scan even though their class is scan-shareable.
-        # Their raw-value states are deliberately NOT in the persistence
-        # registry, so a configured checkpointer would blow up on its first
-        # save; drop it with a warning instead (the same degradation the
-        # mesh path applies), keeping the run correct end to end.
-        if checkpointer is not None and any(
-            getattr(a, "host_exclusive", False) for a in passed
-        ):
-            import logging
-
-            logging.getLogger(__name__).warning(
-                "ingest checkpointing is not supported with host-exclusive "
-                "analyzers (e.g. exact-quantile mode, whose raw-value "
-                "states are not persistable); running without checkpoints"
+            # split: device-fused scan / grouping sets / host accumulators
+            from ..analyzers.grouping import (
+                DeviceFrequencyScan,
+                DeviceFrequencyTableScan,
+                ScanShareableFrequencyBasedAnalyzer,
+                device_freq_enabled,
+                device_freq_max_cardinality,
+                plan_table_scan,
+                probably_low_cardinality,
             )
-            checkpointer = None
-        scanning = [
-            a
-            for a in passed
-            if isinstance(a, ScanShareableAnalyzer)
-            and not getattr(a, "host_exclusive", False)
-        ]
-        scanning_set = set(scanning)
-        grouping = [a for a in passed if isinstance(a, GroupingAnalyzer)]
-        # binning-free Histograms over small-dictionary columns share the
-        # device frequency scan instead of accumulating a host group-by per
-        # batch (their metric is derived from the same counts; keys are
-        # Spark-string-cast at finalize). The reference always runs its own
-        # groupBy per Histogram (`analyzers/Histogram.scala:54-96`).
-        device_hist = [
-            a
-            for a in passed
-            if isinstance(a, Histogram)
-            and a.binning_func is None
-            and (size := data.dictionary_size(a.column)) is not None
-            and size <= dict_card_limit
-        ]
-        device_hist_set = set(device_hist)
-        host_accum = [
-            a
-            for a in passed
-            if hasattr(a, "host_init")
-            and not isinstance(a, GroupingAnalyzer)
-            and a not in device_hist_set
-            and a not in scanning_set
-        ]
-        others = [
-            a
-            for a in passed
-            if a not in scanning_set
-            and a not in grouping
-            and a not in host_accum
-            and a not in device_hist_set
-        ]
 
-        grouping_sets: Dict[Tuple[str, ...], List[GroupingAnalyzer]] = {}
-        for g in grouping:
-            grouping_sets.setdefault(tuple(g.grouping_columns()), []).append(g)
+            dict_card_limit = device_freq_max_cardinality()
 
-        # single-column grouping sets over dictionary-encoded columns whose
-        # dictionary is small ride the fused DEVICE scan as a segment_sum
-        # (SURVEY §7 step 6's low-cardinality hybrid)
-        device_freq: Dict[Tuple[str, ...], DeviceFrequencyScan] = {}
-        device_dicts: Dict[Tuple[str, ...], Any] = {}
-        for cols in list(grouping_sets) + [(a.column,) for a in device_hist]:
-            if cols in device_freq:
-                continue
-            if len(cols) == 1:
-                dictionary = data.dictionary_values(cols[0])
-                if dictionary is not None and len(dictionary) <= dict_card_limit:
-                    device_freq[cols] = DeviceFrequencyScan(cols[0], len(dictionary))
-                    device_dicts[cols] = dictionary
-        # a histogram column whose dictionary out-sizes the device path
-        # falls back to the host accumulator
-        for a in device_hist:
-            if (a.column,) not in device_freq:
-                device_hist_set.discard(a)
-                host_accum.append(a)
-        device_hist = [a for a in device_hist if a in device_hist_set]
+            # host-exclusive analyzers (e.g. exact-quantile mode, whose
+            # accumulator is unbounded and has no fixed-shape device fold) opt
+            # out of the fused scan even though their class is scan-shareable.
+            # Their raw-value states are deliberately NOT in the persistence
+            # registry, so a configured checkpointer would blow up on its first
+            # save; drop it with a warning instead (the same degradation the
+            # mesh path applies), keeping the run correct end to end.
+            if checkpointer is not None and any(
+                getattr(a, "host_exclusive", False) for a in passed
+            ):
+                import logging
 
-        # every OTHER grouping set rides the device frequency TABLE engine
-        # (hashed fixed-shape count tables folded in the fused pass,
-        # ROADMAP item 3) when it safely can:
-        #  - every member reduces the COUNT MULTISET alone (Histogram /
-        #    MutualInformation read keys and stay on the dict/host paths);
-        #  - nothing downstream needs value-keyed states — no persistence,
-        #    aggregation or checkpointing (hashed tables and value-keyed
-        #    host states must never merge);
-        #  - x64 is on (uint64 keys) and the pass will run the DEVICE tier
-        #    (on a feed-starved link streaming 8B/row of raw keys loses to
-        #    the in-place host group-by).
-        # Overflowing tables fall back per set after the pass; the host
-        # accumulator (and its _SpillStore) is the last-resort tier.
-        import jax as _jax
+                logging.getLogger(__name__).warning(
+                    "ingest checkpointing is not supported with host-exclusive "
+                    "analyzers (e.g. exact-quantile mode, whose raw-value "
+                    "states are not persistable); running without checkpoints"
+                )
+                checkpointer = None
+            scanning = [
+                a
+                for a in passed
+                if isinstance(a, ScanShareableAnalyzer)
+                and not getattr(a, "host_exclusive", False)
+            ]
+            scanning_set = set(scanning)
+            grouping = [a for a in passed if isinstance(a, GroupingAnalyzer)]
+            # binning-free Histograms over small-dictionary columns share the
+            # device frequency scan instead of accumulating a host group-by per
+            # batch (their metric is derived from the same counts; keys are
+            # Spark-string-cast at finalize). The reference always runs its own
+            # groupBy per Histogram (`analyzers/Histogram.scala:54-96`).
+            device_hist = [
+                a
+                for a in passed
+                if isinstance(a, Histogram)
+                and a.binning_func is None
+                and (size := data.dictionary_size(a.column)) is not None
+                and size <= dict_card_limit
+            ]
+            device_hist_set = set(device_hist)
+            host_accum = [
+                a
+                for a in passed
+                if hasattr(a, "host_init")
+                and not isinstance(a, GroupingAnalyzer)
+                and a not in device_hist_set
+                and a not in scanning_set
+            ]
+            others = [
+                a
+                for a in passed
+                if a not in scanning_set
+                and a not in grouping
+                and a not in host_accum
+                and a not in device_hist_set
+            ]
 
-        from .engine import effective_batch_size as _ebs
+            grouping_sets: Dict[Tuple[str, ...], List[GroupingAnalyzer]] = {}
+            for g in grouping:
+                grouping_sets.setdefault(tuple(g.grouping_columns()), []).append(g)
 
-        slim = (
-            aggregate_with is None
-            and save_states_with is None
-            and checkpointer is None
-        )
-        table_freq: Dict[Tuple[str, ...], DeviceFrequencyTableScan] = {}
-        if (
-            slim
-            and grouping_sets
-            and device_freq_enabled()
-            and _jax.config.jax_enable_x64
-            and _device_tier_expected(scanning, placement)
-        ):
-            batch_rows = _ebs(data, batch_size)
-            if sharding is not None:
-                from ..parallel import mesh_batch_quantum
-
-                q = mesh_batch_quantum(int(sharding.devices.size))
-                batch_rows = ((batch_rows + q - 1) // q) * q
-            for cols, members in grouping_sets.items():
+            # single-column grouping sets over dictionary-encoded columns whose
+            # dictionary is small ride the fused DEVICE scan as a segment_sum
+            # (SURVEY §7 step 6's low-cardinality hybrid)
+            device_freq: Dict[Tuple[str, ...], DeviceFrequencyScan] = {}
+            device_dicts: Dict[Tuple[str, ...], Any] = {}
+            for cols in list(grouping_sets) + [(a.column,) for a in device_hist]:
                 if cols in device_freq:
                     continue
-                if not all(
-                    isinstance(a, ScanShareableFrequencyBasedAnalyzer)
-                    for a in members
-                ):
-                    continue
-                if probably_low_cardinality(data, cols):
-                    # below the sweep knee the host value_counts fast
-                    # path beats the device table ~3x — keep the
-                    # pre-engine routing for confidently-small sets
-                    continue
-                scan = plan_table_scan(
-                    schema, cols, int(data.num_rows), batch_rows,
-                    sharded=sharding is not None,
-                )
-                if scan is not None:
-                    table_freq[cols] = scan
+                if len(cols) == 1:
+                    dictionary = data.dictionary_values(cols[0])
+                    if dictionary is not None and len(dictionary) <= dict_card_limit:
+                        device_freq[cols] = DeviceFrequencyScan(cols[0], len(dictionary))
+                        device_dicts[cols] = dictionary
+            # a histogram column whose dictionary out-sizes the device path
+            # falls back to the host accumulator
+            for a in device_hist:
+                if (a.column,) not in device_freq:
+                    device_hist_set.discard(a)
+                    host_accum.append(a)
+            device_hist = [a for a in device_hist if a in device_hist_set]
 
-        # one shared pass over the data — executed through the reliability
-        # layer: a device-infrastructure failure fails the battery over to
-        # the host tier (OOMs first bisect the batch size), and an
-        # analyzer-level fault bisects the battery until exactly the faulty
-        # analyzers degrade to typed Failure metrics while the rest
-        # complete (the fused-engine restoration of the reference's
-        # per-expression degradation, `AnalysisRunner.scala:320-323`)
-        scan_battery = (
-            scanning + list(device_freq.values()) + list(table_freq.values())
-        )
-        run_monitor = monitor or RunMonitor()
-        if table_freq:
-            run_monitor.bump("device_freq_sets", len(table_freq))
+            # every OTHER grouping set rides the device frequency TABLE engine
+            # (hashed fixed-shape count tables folded in the fused pass,
+            # ROADMAP item 3) when it safely can:
+            #  - every member reduces the COUNT MULTISET alone (Histogram /
+            #    MutualInformation read keys and stay on the dict/host paths);
+            #  - nothing downstream needs value-keyed states — no persistence,
+            #    aggregation or checkpointing (hashed tables and value-keyed
+            #    host states must never merge);
+            #  - x64 is on (uint64 keys) and the pass will run the DEVICE tier
+            #    (on a feed-starved link streaming 8B/row of raw keys loses to
+            #    the in-place host group-by).
+            # Overflowing tables fall back per set after the pass; the host
+            # accumulator (and its _SpillStore) is the last-resort tier.
+            import jax as _jax
 
-        def make_host_states():
-            hs: Dict[Any, Any] = {}
-            hu: Dict[Any, Any] = {}
-            for cols in grouping_sets:
-                if cols in device_freq or cols in table_freq:
-                    continue
-                key = ("__grouping__", cols)
-                hs[key] = FrequenciesAndNumRows.empty(list(cols))
-                hu[key] = lambda st, batch: st.update(batch)
-            for a in host_accum:
-                hs[a] = a.host_init()
-                hu[a] = a.host_update
-            return hs, hu
+            from .engine import effective_batch_size as _ebs
 
-        host_keys = list(make_host_states()[0])
+            slim = (
+                aggregate_with is None
+                and save_states_with is None
+                and checkpointer is None
+            )
+            table_freq: Dict[Tuple[str, ...], DeviceFrequencyTableScan] = {}
+            if (
+                slim
+                and grouping_sets
+                and device_freq_enabled()
+                and _jax.config.jax_enable_x64
+                and _device_tier_expected(scanning, placement)
+            ):
+                batch_rows = _ebs(data, batch_size)
+                if sharding is not None:
+                    from ..parallel import mesh_batch_quantum
+
+                    q = mesh_batch_quantum(int(sharding.devices.size))
+                    batch_rows = ((batch_rows + q - 1) // q) * q
+                for cols, members in grouping_sets.items():
+                    if cols in device_freq:
+                        continue
+                    if not all(
+                        isinstance(a, ScanShareableFrequencyBasedAnalyzer)
+                        for a in members
+                    ):
+                        continue
+                    if probably_low_cardinality(data, cols):
+                        # below the sweep knee the host value_counts fast
+                        # path beats the device table ~3x — keep the
+                        # pre-engine routing for confidently-small sets
+                        continue
+                    scan = plan_table_scan(
+                        schema, cols, int(data.num_rows), batch_rows,
+                        sharded=sharding is not None,
+                    )
+                    if scan is not None:
+                        table_freq[cols] = scan
+
+            # one shared pass over the data — executed through the reliability
+            # layer: a device-infrastructure failure fails the battery over to
+            # the host tier (OOMs first bisect the batch size), and an
+            # analyzer-level fault bisects the battery until exactly the faulty
+            # analyzers degrade to typed Failure metrics while the rest
+            # complete (the fused-engine restoration of the reference's
+            # per-expression degradation, `AnalysisRunner.scala:320-323`)
+            scan_battery = (
+                scanning + list(device_freq.values()) + list(table_freq.values())
+            )
+            if table_freq:
+                run_monitor.bump("device_freq_sets", len(table_freq))
+
+            def make_host_states():
+                hs: Dict[Any, Any] = {}
+                hu: Dict[Any, Any] = {}
+                for cols in grouping_sets:
+                    if cols in device_freq or cols in table_freq:
+                        continue
+                    key = ("__grouping__", cols)
+                    hs[key] = FrequenciesAndNumRows.empty(list(cols))
+                    hu[key] = lambda st, batch: st.update(batch)
+                for a in host_accum:
+                    hs[a] = a.host_init()
+                    hu[a] = a.host_update
+                return hs, hu
+
+            host_keys = list(make_host_states()[0])
         need_pass = bool(scan_battery) or bool(host_keys)
         metrics: Dict[Analyzer, Metric] = {}
         if need_pass:
@@ -350,16 +354,18 @@ class AnalysisRunner:
                 pass_sharding = (
                     outer_sharding if sharding is _KEEP_SHARDING else sharding
                 )
-                engine = ScanEngine(
-                    list(part), monitor=run_monitor, sharding=pass_sharding,
-                    placement=placement,
-                )
-                g_sets = [
-                    key[1] for key in hs
-                    if isinstance(key, tuple) and key and key[0] == "__grouping__"
-                ]
-                h_accum = [key for key in hs if not isinstance(key, tuple)]
-                cols = _columns_needed(engine, g_sets, h_accum, schema)
+                with run_monitor.timed("plan"):
+                    engine = ScanEngine(
+                        list(part), monitor=run_monitor,
+                        sharding=pass_sharding, placement=placement,
+                    )
+                    g_sets = [
+                        key[1] for key in hs
+                        if isinstance(key, tuple) and key
+                        and key[0] == "__grouping__"
+                    ]
+                    h_accum = [key for key in hs if not isinstance(key, tuple)]
+                    cols = _columns_needed(engine, g_sets, h_accum, schema)
                 # checkpoints belong to the primary full-battery fold only:
                 # bisection re-passes must not clobber its resume point
                 ckpt = checkpointer if tuple(part) == full_battery else None
@@ -387,21 +393,22 @@ class AnalysisRunner:
             fallback_sets: List[Tuple[str, ...]] = []
             fallback_losses: List[str] = []
             if table_freq:
-                for cols, scan in table_freq.items():
-                    state = outcome.states.get(scan)
-                    drained = None if state is None else scan.drain(state)
-                    if drained is None:
-                        fallback_sets.append(cols)
-                        if state is not None:
-                            run_monitor.bump("freq_overflow_fallbacks")
-                            fallback_losses.append(
-                                f"{cols}: ~{int(state.lost_groups)} groups / "
-                                f"{int(state.lost_rows)} rows dropped"
-                            )
+                with run_monitor.timed("freq_drain"):
+                    for cols, scan in table_freq.items():
+                        state = outcome.states.get(scan)
+                        drained = None if state is None else scan.drain(state)
+                        if drained is None:
+                            fallback_sets.append(cols)
+                            if state is not None:
+                                run_monitor.bump("freq_overflow_fallbacks")
+                                fallback_losses.append(
+                                    f"{cols}: ~{int(state.lost_groups)} groups / "
+                                    f"{int(state.lost_rows)} rows dropped"
+                                )
+                            else:
+                                fallback_losses.append(f"{cols}: pass degraded")
                         else:
-                            fallback_losses.append(f"{cols}: pass degraded")
-                    else:
-                        table_shared[cols] = drained
+                            table_shared[cols] = drained
             if fallback_sets:
                 import logging
 

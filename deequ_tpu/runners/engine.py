@@ -14,7 +14,10 @@ observables so tests can assert scan-sharing invariants, not just values.
 
 from __future__ import annotations
 
+import contextlib
 import logging
+import re
+import time
 from collections import OrderedDict
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
@@ -39,9 +42,6 @@ _logger = logging.getLogger(__name__)
 #: "host"/"device" pin the tier). Read through `utils.env_str` so the
 #: env-knob convention check (tools/statlint) can see every read site.
 PLACEMENT_ENV = "DEEQU_TPU_PLACEMENT"
-
-#: env var: directory receiving a `jax.profiler` trace of every pass
-PROFILE_DIR_ENV = "DEEQU_TPU_PROFILE_DIR"
 
 
 @dataclass
@@ -231,10 +231,11 @@ class RunMonitor:
         with _MONITOR_LOCK:
             self.phase_seconds[phase] = self.phase_seconds.get(phase, 0.0) + seconds
 
-    def timed(self, phase: str):
+    def timed(self, phase: str, **attrs: Any):
         """Context manager accumulating wall time under ``phase``; safe to
-        use from the prefetch/ingest worker threads."""
-        return _PhaseTimer(self, phase)
+        use from the prefetch/ingest worker threads. ``attrs`` ride on the
+        phase's span and profiler annotation."""
+        return _PhaseTimer(self, phase, attrs)
 
 
 import threading as _threading  # noqa: E402
@@ -253,31 +254,48 @@ _PROGRAM_CACHE_LOCK = _threading.Lock()
 _CACHE_BYPASS = _threading.local()
 
 
+def _phase(monitor: Optional[RunMonitor], phase: str, **attrs: Any):
+    """``monitor.timed(phase)``, or nothing without a monitor."""
+    if monitor is None:
+        return contextlib.nullcontext()
+    return monitor.timed(phase, **attrs)
+
+
 class _PhaseTimer:
-    """Span-backed phase timer: the measured interval both accumulates into
-    ``phase_seconds`` (unchanged numbers, now derived from the same ns
-    clock) and, when the calling thread carries a trace context, publishes
-    as a finished child span — so a trace's phase durations can never
-    disagree with the monitor's."""
+    """Span-backed phase timer: the measured interval accumulates into
+    ``phase_seconds``, publishes as a finished child span when the calling
+    thread carries a trace context, and is bracketed by a
+    ``jax.profiler.TraceAnnotation`` named ``deequ/<phase>`` — so a trace's
+    phase durations can never disagree with the monitor's, and a device
+    trace shows each phase on the host thread that ran it, on the device
+    ops' clock. ``DEEQU_TPU_TRACE=0`` turns the span and the annotation
+    off; ``phase_seconds`` is always kept. ``seconds`` holds the interval
+    after exit."""
 
-    __slots__ = ("monitor", "phase", "t0_ns")
+    __slots__ = ("monitor", "phase", "attrs", "t0_ns", "seconds", "_annotation")
 
-    def __init__(self, monitor: RunMonitor, phase: str):
+    def __init__(self, monitor: RunMonitor, phase: str, attrs: Dict[str, Any]):
         self.monitor = monitor
         self.phase = phase
+        self.attrs = attrs
 
     def __enter__(self):
-        import time
-
+        self._annotation = None
+        if _trace.enabled():
+            self._annotation = jax.profiler.TraceAnnotation(
+                "deequ/" + self.phase, **self.attrs
+            )
+            self._annotation.__enter__()
         self.t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        import time
-
         end_ns = time.perf_counter_ns()
-        self.monitor.add_phase_time(self.phase, (end_ns - self.t0_ns) / 1e9)
-        _trace.record_phase(self.phase, self.t0_ns, end_ns)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        self.seconds = (end_ns - self.t0_ns) / 1e9
+        self.monitor.add_phase_time(self.phase, self.seconds)
+        _trace.record_phase(self.phase, self.t0_ns, end_ns, self.attrs)
         return False
 
 
@@ -293,6 +311,33 @@ class _PhaseTimer:
 from ..utils import BoundedLRU as _BoundedLRU  # noqa: E402
 
 _PROGRAM_CACHE = _BoundedLRU(256)
+
+
+#: short family names of analyzer classes in program names; every other
+#: class reads as its name in snake case
+_PROGRAM_FAMILIES = {
+    "ApproxCountDistinct": "hll",
+    "KLLSketch": "kll",
+    "DeviceFrequencyScan": "freqdict",
+    "DeviceFrequencyTableScan": "freqtable",
+}
+
+
+def _family(cls: type) -> str:
+    name = cls.__name__
+    family = _PROGRAM_FAMILIES.get(name)
+    if family is None:
+        family = re.sub(r"(?<=[a-z0-9])(?=[A-Z])", "_", name).lower()
+    return family
+
+
+def program_name(analyzers: Sequence[ScanShareableAnalyzer]) -> str:
+    """A bundle program's name, e.g. ``scan_kll_x8``: its analyzers'
+    families in first-appearance order and its slot count. Built from
+    class names alone, so it is the same in every process (the persistent
+    compile cache keys on the lowered module, which carries the name)."""
+    families = dict.fromkeys(_family(type(a)) for a in analyzers)
+    return f"scan_{'_'.join(families)}_x{len(analyzers)}"
 
 
 class PackedScanProgram:
@@ -334,6 +379,8 @@ class PackedScanProgram:
         #: compiles lazily, so mere construction leaves the program cold —
         #: warmth claims (the service's cache-aware placement) key on this
         self.executed = False
+        #: the jitted update's name (``jit_<name>`` in a device trace)
+        self.name = program_name(analyzers)
         #: per-slot feature keys of the TEMPLATE analyzers this program was
         #: traced with; callers with same-signature batteries feed arrays
         #: positionally and the trace rebinds them under these keys
@@ -375,6 +422,7 @@ class PackedScanProgram:
                 )
             )
 
+        fused_update.__name__ = fused_update.__qualname__ = self.name
         if mesh is None:
             self._update = jax.jit(fused_update, donate_argnums=0)
         else:
@@ -756,6 +804,7 @@ class BundledScanProgram:
         features: Dict[str, jax.Array],
         ledger: Optional[_CostLedger] = None,
         probe: bool = False,
+        monitor: Optional[RunMonitor] = None,
     ):
         """Dispatch one batch. With ``ledger`` (a pass-local
         :class:`_CostLedger`), each bundle's dispatch wall time is measured
@@ -764,36 +813,63 @@ class BundledScanProgram:
         synchronous trace/XLA compile. ``probe=True`` brackets each bundle
         with ``block_until_ready`` so this batch's measurement is TRUE
         execution time — the engine schedules one probe every
-        ``_COST_PROBE_EVERY`` batches, bounding the sync overhead."""
-        import time as _time
+        ``_COST_PROBE_EVERY`` batches, bounding the sync overhead.
 
+        With ``monitor``, the batch's enqueue times under
+        ``device_dispatch``; a bundle program's first dispatch in the
+        process (trace, lower, compile or cache load) times under
+        ``program_load`` instead, named by the program, and the probe's
+        waits under ``device_wait``."""
+        probe = probe and ledger is not None
+        if monitor is None or not (probe or self.cold_programs()):
+            with _phase(monitor, "device_dispatch"):
+                out = self._dispatch(carry, features, ledger, probe, None)
+        else:
+            out = self._dispatch(carry, features, ledger, probe, monitor)
+        if probe and self._programs:
+            # one probe per probed BATCH (the documented unit), however
+            # many bundles the battery spans
+            ledger.probes += 1
+        self.executed = True
+        return out
+
+    def _dispatch(self, carry, features, ledger, probe, monitor) -> Tuple:
+        """Each bundle's dispatch, timed per bundle when ``monitor`` is
+        given (see :meth:`__call__`)."""
         out = []
         for c, prog, keys, reprs in zip(
             carry, self._programs, self._slot_keys, self._slot_reprs
         ):
             slots = tuple(tuple(features[k] for k in slot) for slot in keys)
-            if ledger is None:
-                out.append(prog.call_with_slots(c, slots))
-                continue
             if probe:
-                jax.block_until_ready(jax.tree_util.tree_leaves(c))
-            t0 = _time.perf_counter()
-            result = prog.call_with_slots(c, slots)
+                with _phase(monitor, "device_wait"):
+                    jax.block_until_ready(jax.tree_util.tree_leaves(c))
+            t0 = time.perf_counter()
+            if monitor is not None and not prog.executed:
+                with monitor.timed("program_load", program=prog.name) as load:
+                    result = prog.call_with_slots(c, slots)
+                # the per-program breakdown names the program in
+                # ``phase_seconds``; it is part of ``program_load``
+                monitor.add_phase_time(f"program_load.{prog.name}", load.seconds)
+            else:
+                with _phase(monitor, "device_dispatch"):
+                    result = prog.call_with_slots(c, slots)
             if probe:
-                jax.block_until_ready(jax.tree_util.tree_leaves(result))
+                with _phase(monitor, "device_wait"):
+                    jax.block_until_ready(jax.tree_util.tree_leaves(result))
             out.append(result)
-            ledger.add_bundle(reprs, _time.perf_counter() - t0)
-        if probe and ledger is not None and self._programs:
-            # one probe per probed BATCH (the documented unit), however
-            # many bundles the battery spans
-            ledger.probes += 1
-        self.executed = True
+            if ledger is not None:
+                ledger.add_bundle(reprs, time.perf_counter() - t0)
         return tuple(out)
 
     def cold_programs(self) -> int:
         """Bundle programs that have never dispatched (each compiles on
         its first batch)."""
         return sum(not p.executed for p in self._programs)
+
+    def cold_names(self) -> List[str]:
+        """Names of the bundle programs that have never dispatched."""
+        return list(dict.fromkeys(p.name for p in self._programs if not p.executed))
 
     def unpack(self, carry) -> Tuple:
         """Per-analyzer state pytrees in battery order (pad slots, which
@@ -837,6 +913,7 @@ class BundledScanProgram:
 def fold_sessions_coalesced(
     orchestrators: Sequence[BundledScanProgram],
     features_list: Sequence[Dict[str, np.ndarray]],
+    monitor: Optional[RunMonitor] = None,
 ) -> List[Tuple]:
     """Fold W same-signature sessions' single padded batches as ONE device
     launch per signature bundle (the cross-session coalescer's device arm).
@@ -854,7 +931,12 @@ def fold_sessions_coalesced(
     group — the per-session fixed cost this path exists to amortize.
     Returns per REAL session a tuple of host state pytrees in battery
     order. Mesh-free only (service streaming sessions coalesce; GSPMD
-    passes keep the serial path)."""
+    passes keep the serial path).
+
+    With ``monitor``, the launch times under the engine's phases:
+    ``device_dispatch`` (stacking the sessions' batches and enqueueing
+    the programs, which copies the stacked batch to the device),
+    ``device_wait`` and ``state_fetch``."""
     template = orchestrators[0]
     if template.mesh is not None:
         raise ValueError("coalesced folds are mesh-free by design")
@@ -881,12 +963,16 @@ def fold_sessions_coalesced(
             )
             for s in range(len(template._slot_keys[j]))
         )
-        carry = bprog.init_carry_stacked(width)
-        out = bprog.call_with_slots_stacked(carry, stacked_slots)
-        states = bprog.unpack_stacked_final(out)
+        with _phase(monitor, "device_dispatch"):
+            carry = bprog.init_carry_stacked(width)
+            out = bprog.call_with_slots_stacked(carry, stacked_slots)
+            states = bprog.unpack_stacked_final(out)
         for k in range(n_real_slots):
             stacked_states[idxs[k]] = states[k]
-    fetched = _fetch_states_packed(tuple(stacked_states))
+    with _phase(monitor, "device_wait"):
+        jax.block_until_ready(jax.tree_util.tree_leaves(stacked_states))
+    with _phase(monitor, "state_fetch"):
+        fetched = _fetch_states_packed(tuple(stacked_states))
     return [
         tuple(
             jax.tree_util.tree_map(lambda x, _w=w: x[_w], st)
@@ -1955,98 +2041,83 @@ class ScanEngine:
         checkpoint) — each analyzer's non-metric-bearing leaves then skip
         the feed link and are reconstructed from identity values.
 
-        Set ``DEEQU_TPU_PROFILE_DIR`` to capture a ``jax.profiler`` trace of
-        every pass into that directory (SURVEY §5's optional profiler hook;
-        view with tensorboard or Perfetto). The lightweight phase timers in
-        RunMonitor are always on."""
-        import contextlib
+        The phase timers in RunMonitor are always on; under a
+        ``jax.profiler`` trace their ``deequ/<phase>`` annotations show where
+        each pass spent its host time."""
+        from ..reliability.watchdog import (
+            rate_tracker,
+            run_with_deadline,
+            scan_deadline_s,
+        )
 
-        from ..utils import env_str
+        bs = effective_batch_size(data, batch_size)
+        n_batches = max(1, -(-int(data.num_rows) // bs))
+        n_rows = max(1, int(data.num_rows))
+        tier = self._resolve_placement_inner()
+        cold = 0
+        if tier == "device" and self._update is not None:
+            cold = self._update.cold_programs()
+        deadline = scan_deadline_s(n_rows, tier, cold)
+        bypass = getattr(_CACHE_BYPASS, "active", False)
+        batches_before = self.monitor.batches
+        t0 = time.perf_counter()
+        with _trace.span(
+            "engine_pass", kind="engine", tier=tier, rows=n_rows,
+            batches=n_batches, analyzers=len(self.scan_analyzers),
+        ):
+            if deadline is None:
+                result = self._run_inner(
+                    data, batch_size, host_accumulators, host_update_fns,
+                    columns, checkpointer, slim_fetch,
+                )
+            else:
+                # the pass body moves to the watchdog's worker thread;
+                # the per-thread cache-bypass flag (background warm
+                # runs) and the trace context must move with it, or a
+                # warm sample would enter the budget and the pass's
+                # phases would orphan into a fresh trace
+                ctx = _trace.capture()
 
-        profile_dir = env_str(PROFILE_DIR_ENV)
-        if profile_dir:
-            import jax.profiler
-
-            tracer = jax.profiler.trace(profile_dir)
-        else:
-            tracer = contextlib.nullcontext()
-        with tracer:
-            from ..reliability.watchdog import (
-                rate_tracker,
-                run_with_deadline,
-                scan_deadline_s,
-            )
-
-            bs = effective_batch_size(data, batch_size)
-            n_batches = max(1, -(-int(data.num_rows) // bs))
-            n_rows = max(1, int(data.num_rows))
-            tier = self._resolve_placement_inner()
-            cold = 0
-            if tier == "device" and self._update is not None:
-                cold = self._update.cold_programs()
-            deadline = scan_deadline_s(n_rows, tier, cold)
-            bypass = getattr(_CACHE_BYPASS, "active", False)
-            import time
-
-            batches_before = self.monitor.batches
-            t0 = time.perf_counter()
-            with _trace.span(
-                "engine_pass", kind="engine", tier=tier, rows=n_rows,
-                batches=n_batches, analyzers=len(self.scan_analyzers),
-            ):
-                if deadline is None:
-                    result = self._run_inner(
-                        data, batch_size, host_accumulators, host_update_fns,
-                        columns, checkpointer, slim_fetch,
-                    )
-                else:
-                    # the pass body moves to the watchdog's worker thread;
-                    # the per-thread cache-bypass flag (background warm
-                    # runs) and the trace context must move with it, or a
-                    # warm sample would enter the budget and the pass's
-                    # phases would orphan into a fresh trace
-                    ctx = _trace.capture()
-
-                    def pass_body():
-                        _CACHE_BYPASS.active = bypass
-                        with _trace.attach(ctx):
-                            return self._run_inner(
-                                data, batch_size, host_accumulators,
-                                host_update_fns, columns, checkpointer,
-                                slim_fetch,
-                            )
-
-                    from ..exceptions import ScanStallError
-
-                    try:
-                        result = run_with_deadline(
-                            pass_body, deadline, self.monitor, tier
+                def pass_body():
+                    _CACHE_BYPASS.active = bypass
+                    with _trace.attach(ctx):
+                        return self._run_inner(
+                            data, batch_size, host_accumulators,
+                            host_update_fns, columns, checkpointer,
+                            slim_fetch,
                         )
-                    except ScanStallError:
-                        # the abandoned zombie must stop reporting costs
-                        # into this monitor (best-effort: a flush already
-                        # in flight at this instant is the same bounded
-                        # race the rate tracker tolerates)
-                        self._cancelled.set()
-                        raise
-            # only COMPLETED passes teach the rate tracker, and only
-            # REPRESENTATIVE ones: background warm runs (1-row samples
-            # under the cache bypass) and the batches a resume skipped
-            # would both poison the EWMA toward a deadline no production
-            # pass can meet — observe the batches this pass actually
-            # processed (the monitor delta), never the nominal count. A
-            # delta EXCEEDING the pass's own batch count proves another
-            # pass (a watchdog-abandoned zombie, an overlapped profile
-            # scan) bumped the shared monitor concurrently — skip the
-            # observation rather than learn a contaminated rate
-            if not bypass:
-                folded = self.monitor.batches - batches_before
-                if 0 < folded <= n_batches:
-                    rate_tracker().observe(
-                        tier, min(folded * bs, n_rows),
-                        time.perf_counter() - t0,
+
+                from ..exceptions import ScanStallError
+
+                try:
+                    result = run_with_deadline(
+                        pass_body, deadline, self.monitor, tier
                     )
-            return result
+                except ScanStallError:
+                    # the abandoned zombie must stop reporting costs
+                    # into this monitor (best-effort: a flush already
+                    # in flight at this instant is the same bounded
+                    # race the rate tracker tolerates)
+                    self._cancelled.set()
+                    raise
+        # only COMPLETED passes teach the rate tracker, and only
+        # REPRESENTATIVE ones: background warm runs (1-row samples
+        # under the cache bypass) and the batches a resume skipped
+        # would both poison the EWMA toward a deadline no production
+        # pass can meet — observe the batches this pass actually
+        # processed (the monitor delta), never the nominal count. A
+        # delta EXCEEDING the pass's own batch count proves another
+        # pass (a watchdog-abandoned zombie, an overlapped profile
+        # scan) bumped the shared monitor concurrently — skip the
+        # observation rather than learn a contaminated rate
+        if not bypass:
+            folded = self.monitor.batches - batches_before
+            if 0 < folded <= n_batches:
+                rate_tracker().observe(
+                    tier, min(folded * bs, n_rows),
+                    time.perf_counter() - t0,
+                )
+        return result
 
     def _run_inner(
         self,
@@ -2160,7 +2231,9 @@ class ScanEngine:
         def produce_inner():
             index = next(idx_counter)
             try:
-                batch = next(batches)
+                # Arrow slicing, column materialization and padding
+                with monitor.timed("batch_slice"):
+                    batch = next(batches)
             except StopIteration:
                 return None
             if self._update is None:
@@ -2178,7 +2251,20 @@ class ScanEngine:
                 return batch, features
             return batch, self._prepare(batch)
 
-        carry = self._update.init_carry() if self._update is not None else None
+        # a pass that brings bundle programs to their first dispatch also
+        # loads their init and unpack programs: time those under
+        # program_load too, so device_dispatch stays enqueue only
+        loading = self._update.cold_names() if self._update is not None else []
+
+        def dispatch_phase():
+            if loading:
+                return monitor.timed("program_load", program=",".join(loading))
+            return monitor.timed("device_dispatch")
+
+        carry = None
+        if self._update is not None:
+            with dispatch_phase():
+                carry = self._update.init_carry()
         cost_ledger = _CostLedger()
         folded = 0
         if resume is not None:
@@ -2221,16 +2307,25 @@ class ScanEngine:
         with PrefetchingBatchIterator(
             produce, depth=staging_depth(n_total_batches)
         ) as staged:
-            for item in staged:
+            while True:
+                if staged.depth > 0:
+                    # this thread waits on the feed thread's staging
+                    with monitor.timed("feed_wait"):
+                        item = next(staged, None)
+                else:
+                    # inline staging: its own phases time the build here
+                    item = next(staged, None)
+                if item is None:
+                    break
                 batch, features = item
                 monitor.bump("batches")
                 if features is not None:
                     fault_point("device_update", tag=str(folded + 1))
-                    with monitor.timed("device_dispatch"):
-                        carry = self._update(
-                            carry, features, ledger=cost_ledger,
-                            probe=(folded % _COST_PROBE_EVERY == 1),
-                        )
+                    carry = self._update(
+                        carry, features, ledger=cost_ledger,
+                        probe=(folded % _COST_PROBE_EVERY == 1),
+                        monitor=monitor,
+                    )
                     monitor.bump("device_updates")
                 with monitor.timed("host_accumulators"):
                     for key, fn in update_fns.items():
@@ -2241,14 +2336,14 @@ class ScanEngine:
         if ckpt is not None:
             ckpt.complete(ckpt_epoch)
         if carry is not None:
-            # drain the async dispatch queue UNDER the dispatch timer:
-            # device execution time belongs to device_dispatch, so the
-            # state_fetch phase measures the transfer alone (previously the
-            # blocking fetch absorbed all queued compute and the warm
-            # profile read as fetch-bound when it was not)
-            with monitor.timed("device_dispatch"):
+            # drain the async dispatch queue under its own timer, so
+            # device_dispatch stays enqueue only and the state_fetch phase
+            # measures the transfer alone (the blocking fetch would absorb
+            # all queued compute)
+            with monitor.timed("device_wait"):
                 jax.block_until_ready(jax.tree_util.tree_leaves(carry))
-            states = self._update.unpack_final(carry)
+            with dispatch_phase():
+                states = self._update.unpack_final(carry)
             carry = None  # donated — it must never be touched again
         compiled = compiled_count()
         with _MONITOR_LOCK:

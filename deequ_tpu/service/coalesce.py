@@ -1432,7 +1432,7 @@ class FoldCoalescer:
     def _launch(self, prepped) -> List[Tuple]:
         from ..observability import trace as _trace
         from ..reliability.faults import fault_point
-        from ..runners.engine import fold_sessions_coalesced
+        from ..runners.engine import RunMonitor, fold_sessions_coalesced
 
         width = len(prepped)
         rows = int(prepped[0][1].num_rows)
@@ -1454,13 +1454,19 @@ class FoldCoalescer:
             t0 = time.perf_counter()
             orchestrators = [f.plan.orchestrator() for f, _, _, _ in prepped]
             feats = [p[3] for p in prepped]
-            states_list = fold_sessions_coalesced(orchestrators, feats)
+            # the launch's phases, split evenly over its sessions below:
+            # device_dispatch, device_wait and state_fetch, as in a pass
+            launch = RunMonitor()
+            states_list = fold_sessions_coalesced(
+                orchestrators, feats, monitor=launch
+            )
             elapsed = time.perf_counter() - t0
         self.router.observe_device(rows, elapsed, width)
         share = elapsed / width
         controller = getattr(self.service, "tuning_controller", None)
         for f, _, _, _ in prepped:
-            f.monitor.add_phase_time("device_dispatch", share)
+            for phase, seconds in launch.phase_seconds.items():
+                f.monitor.add_phase_time(phase, seconds / width)
             if controller is not None:
                 controller.record(rows, share, arm=f.tuning_arm)
         self._note_width(width, coalesced=True)

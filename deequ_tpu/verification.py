@@ -167,7 +167,7 @@ class VerificationSuite:
                 placement=placement,
                 checkpointer=checkpointer,
             )
-            with _trace.span("constraint_evaluation", kind="phase"):
+            with monitor.timed("constraint_evaluation"):
                 result = VerificationSuite.evaluate(checks, analysis_results)
             result.cost_by_analyzer = dict(monitor.cost_by_analyzer)
             if metrics_repository is not None and save_or_append_results_with_key is not None:
@@ -225,7 +225,7 @@ class VerificationSuite:
                 save_or_append_results_with_key=save_or_append_results_with_key,
                 delete_dropped=delete_dropped,
             )
-            with _trace.span("constraint_evaluation", kind="phase"):
+            with monitor.timed("constraint_evaluation"):
                 result = VerificationSuite.evaluate(checks, context)
             result.cost_by_analyzer = dict(monitor.cost_by_analyzer)
         return IncrementalVerificationResult(result, report)

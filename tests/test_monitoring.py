@@ -79,9 +79,10 @@ class TestPhaseTimers:
         AnalysisRunner.do_analysis_run(
             _data(0), BATTERY, batch_size=4096, monitor=mon, placement="device"
         )
-        assert {"feature_build", "device_feed", "device_dispatch", "state_fetch"} <= set(
-            mon.phase_seconds
-        )
+        assert {
+            "feature_build", "device_feed", "device_dispatch", "device_wait",
+            "state_fetch",
+        } <= set(mon.phase_seconds)
         assert all(v >= 0 for v in mon.phase_seconds.values())
 
     def test_host_path_phases_recorded(self):

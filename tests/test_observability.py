@@ -148,7 +148,9 @@ class TestPhaseSpans:
                 )
         # every monitored phase that ran shows up span-backed, and the
         # span-summed duration equals the monitor's number (same clock)
-        for phase in ("feature_build", "device_dispatch", "state_fetch"):
+        for phase in (
+            "feature_build", "device_dispatch", "device_wait", "state_fetch",
+        ):
             assert phase in phase_totals
             assert phase_totals[phase] == pytest.approx(
                 mon.phase_seconds[phase], rel=1e-6, abs=1e-9
