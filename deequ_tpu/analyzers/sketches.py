@@ -103,14 +103,11 @@ class ApproxCountDistinct(StandardScanShareableAnalyzer[ApproxCountDistinctState
             # dictionary column: hash the DISTINCT values once (cached in
             # col.aux across batches), then max-scatter only the entries
             # present in this batch — O(rows) code counting + O(dict) scatter
-            from ..ops.hll import M, hll_features
-            from ..runners.features import dict_entry_hashes
+            from ..ops.hll import M
+            from ..runners.features import dict_entry_hll_pairs
 
-            pairs = col.aux.get("hll_pairs")
-            if pairs is None:
-                # derives from the shared distinct-value hash pass
-                pairs = hll_features(dict_entry_hashes(col))
-                col.aux["hll_pairs"] = pairs
+            # the same per-entry pairs the device feature packs
+            pairs = dict_entry_hll_pairs(col)
             num_cats = col.num_categories
             if not num_cats:
                 return ApproxCountDistinctState(np.zeros(M, dtype=np.int32))

@@ -164,14 +164,18 @@ def hll_features(hashes: np.ndarray) -> np.ndarray:
     return np.stack([idx, pw.astype(np.int32)])
 
 
+def hll_pack_pairs(pairs: np.ndarray) -> np.ndarray:
+    """uint16 (idx << 6) | pw of ``hll_features`` pairs — the wire format of
+    the HLL feature (2 bytes/row instead of 8; pw <= 57 fits 6 bits, idx <
+    512 fits 10)."""
+    return (pairs[0].astype(np.uint16) << np.uint16(6)) | pairs[1].astype(np.uint16)
+
+
 def hll_pack_features(hashes: np.ndarray, mask: Optional[np.ndarray] = None) -> np.ndarray:
-    """uint16 (idx << 6) | pw per hash — the wire format of the HLL feature
-    (2 bytes/row instead of 8; pw <= 57 fits 6 bits, idx < 512 fits 10).
-    Masked rows pack as 0, which never wins a register max. numpy fallback
-    for the native C++ single-pass kernels (native/lib.py hll_pack_*)."""
-    pairs = hll_features(hashes)
-    packed = ((pairs[0].astype(np.uint16) << np.uint16(6))
-              | pairs[1].astype(np.uint16))
+    """uint16 packed HLL feature per hash (``hll_pack_pairs``). Masked rows
+    pack as 0, which never wins a register max. numpy fallback for the
+    native C++ single-pass kernels (native/lib.py hll_pack_*)."""
+    packed = hll_pack_pairs(hll_features(hashes))
     if mask is not None:
         packed = np.where(mask, packed, np.uint16(0))
     return packed

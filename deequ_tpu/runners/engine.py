@@ -113,6 +113,9 @@ class RunMonitor:
     #: device frequency tables whose compactions dropped groups — those
     #: sets re-ran through the host accumulator last-resort tier
     freq_overflow_fallbacks: int = 0
+    #: (column, batch) HLL features served from a dictionary column's
+    #: per-entry packed table instead of hashed per row
+    dict_hll_gathers: int = 0
     #: mesh shards (devices/processes) declared lost mid-pass — dead
     #: collectives, injected mesh_loss faults, heartbeat-declared stalls
     shard_losses: int = 0
@@ -172,6 +175,7 @@ class RunMonitor:
         self.cost_probes = 0
         self.device_freq_sets = 0
         self.freq_overflow_fallbacks = 0
+        self.dict_hll_gathers = 0
         self.shard_losses = 0
         self.mesh_reshards = 0
         self.salvaged_states = 0
@@ -196,7 +200,8 @@ class RunMonitor:
                 "device_failovers", "batch_bisections", "isolation_reruns",
                 "checkpoint_saves", "corrupt_quarantined", "stalls",
                 "device_stalls", "device_freq_sets",
-                "freq_overflow_fallbacks", "shard_losses", "mesh_reshards",
+                "freq_overflow_fallbacks", "dict_hll_gathers",
+                "shard_losses", "mesh_reshards",
                 "salvaged_states", "fast_path_folds", "coalesced_folds",
                 "fleet_mesh_folds", "cost_probes", "partitions_scanned",
                 "partitions_reused", "partitions_invalidated",
@@ -2003,7 +2008,7 @@ class ScanEngine:
         the prefetch thread so it overlaps the previous batch's device work
         (numpy / pyarrow / the native C++ kernels all release the GIL)."""
         with self.monitor.timed("feature_build"):
-            features = self.builder.build(batch)
+            features = self.builder.build(batch, self.monitor)
         fault_point("device_feed")
         with self.monitor.timed("device_feed"):
             if self.mesh is not None:

@@ -21,18 +21,16 @@ from ..analyzers.base import FeatureSpec
 from ..data import Batch, ColumnKind
 from ..expr import evaluate_predicate
 from ..ops.hashing import hash_column
-from ..ops.hll import hll_pack_features
+from ..ops.hll import hll_features, hll_pack_features, hll_pack_pairs
 
 
 def _hll_packed(col) -> np.ndarray:
-    """uint16 HLL ingest feature for one column — native C++ single pass
-    (hash + clz + pack) when built, numpy fallback otherwise."""
+    """uint16 HLL ingest feature for a column that is not a dictionary
+    STRING column (those gather ``dict_hll_packed``) — native C++ single
+    pass (hash + clz + pack) when built, numpy fallback otherwise."""
     from ..native import native_hll_pack_numeric, native_hll_pack_strings
     from ..ops.hashing import DEFAULT_SEED
 
-    if _is_string_dict(col):
-        # hash the DISTINCT values once per dataset, gather per row
-        return hll_pack_features(dict_hashes(col), col.mask)
     if col.kind == ColumnKind.STRING:
         if native_hll_pack_strings is not None:
             src = col.string_source
@@ -154,17 +152,11 @@ def dict_regex_matches(col, pattern: str) -> np.ndarray:
     entry is matched once per dataset (cached in col.aux, keyed by
     pattern) under Python `re` — exact semantics at O(distinct) cost —
     then gathered by code. Null/padding rows -> False."""
-    key = ("regex", pattern)
-    per_entry = col.aux.get(key)
-    if per_entry is None:
+    def per_entry():
         ones = np.ones(col.num_categories, dtype=bool)
-        per_entry = regex_matches(col.dictionary_source, ones, pattern)
-        col.aux[key] = per_entry
-    num_cats = col.num_categories
-    if not num_cats:
-        return np.zeros(len(col.codes), dtype=bool)
-    safe = np.where(col.codes < num_cats, col.codes, 0)
-    return per_entry[safe] & col.mask
+        return regex_matches(col.dictionary_source, ones, pattern)
+
+    return _gather_by_code(col, _entry_table(col, ("regex", pattern), per_entry, bool))
 
 
 def column_regex_matches(col, pattern: str) -> np.ndarray:
@@ -177,38 +169,79 @@ def column_regex_matches(col, pattern: str) -> np.ndarray:
     return regex_matches(col.values, col.mask, pattern)
 
 
+def _entry_table(col, key, per_entry, dtype) -> np.ndarray:
+    """A dictionary column's per-entry table, cached in ``col.aux[key]``
+    once per dataset: ``per_entry()`` for each DISTINCT value, then one
+    sentinel slot at index ``num_categories`` holding 0 — the code that
+    null and padding rows carry, so a gather needs no bounds pass."""
+    table = col.aux.get(key)
+    if table is None:
+        table = np.zeros(col.num_categories + 1, dtype=dtype)
+        table[:-1] = per_entry()
+        col.aux[key] = table  # published whole: concurrent feature builds race benignly
+    return table
+
+
+def _gather_by_code(col, table: np.ndarray) -> np.ndarray:
+    """Per-row values of a sentinel-slot ``_entry_table``; rows where
+    ``col.mask`` is False read the sentinel's 0."""
+    codes = col.codes
+    if not col.mask.all():
+        codes = np.where(col.mask, codes, len(table) - 1)
+    return np.take(table, codes)  # ~1.6x faster than table[codes]
+
+
+def _dict_type_table(col) -> np.ndarray:
+    def per_entry():
+        ones = np.ones(col.num_categories, dtype=bool)
+        return classify_type_codes(col.dictionary_source, ones, ColumnKind.STRING)
+
+    return _entry_table(col, "type_codes", per_entry, np.int32)
+
+
 def dict_entry_type_codes(col) -> np.ndarray:
     """Type codes of each DISTINCT dictionary value, classified once per
     dataset (cached in col.aux across batches)."""
-    tc = col.aux.get("type_codes")
-    if tc is None:
-        ones = np.ones(col.num_categories, dtype=bool)
-        tc = classify_type_codes(col.dictionary_source, ones, ColumnKind.STRING)
-        col.aux["type_codes"] = tc
-    return tc
+    return _dict_type_table(col)[:-1]
 
 
 def dict_type_codes(col) -> np.ndarray:
     """Per-row type codes for a dictionary STRING column: classify the
-    DISTINCT values once, gather by code. Null/padding rows -> TYPE_NULL."""
-    tc = dict_entry_type_codes(col)
-    num_cats = col.num_categories
-    safe = np.where(col.codes < num_cats, col.codes, 0)
-    out = tc[safe] if num_cats else np.zeros(len(col.codes), dtype=np.int32)
-    out = np.where(col.mask, out, TYPE_NULL).astype(np.int32)
-    return out
+    DISTINCT values once, gather by code. Null/padding rows read the
+    sentinel's 0, which is TYPE_NULL."""
+    return _gather_by_code(col, _dict_type_table(col))
 
 
 def dict_string_lengths(col) -> np.ndarray:
-    ld = col.aux.get("lengths")
-    if ld is None:
+    """Per-row string lengths for a dictionary STRING column: measure the
+    DISTINCT values once, gather by code. Null/padding rows -> 0."""
+    def per_entry():
         ones = np.ones(col.num_categories, dtype=bool)
-        ld = string_lengths(col.dictionary_source, ones)
-        col.aux["lengths"] = ld
-    num_cats = col.num_categories
-    safe = np.where(col.codes < num_cats, col.codes, 0)
-    out = ld[safe] if num_cats else np.zeros(len(col.codes), dtype=np.int32)
-    return np.where(col.mask, out, 0).astype(np.int32)
+        return string_lengths(col.dictionary_source, ones)
+
+    return _gather_by_code(col, _entry_table(col, "lengths", per_entry, np.int32))
+
+
+def dict_entry_hll_pairs(col) -> np.ndarray:
+    """(2, num_categories) HLL register index and leading-zero count of each
+    DISTINCT dictionary value, cached per dataset — the one table both the
+    host tier's partials and the device feature (``dict_hll_packed``)
+    read."""
+    pairs = col.aux.get("hll_pairs")
+    if pairs is None:
+        pairs = hll_features(dict_entry_hashes(col))
+        col.aux["hll_pairs"] = pairs
+    return pairs
+
+
+def dict_hll_packed(col) -> np.ndarray:
+    """Per-row uint16 HLL feature for a dictionary STRING column: each
+    DISTINCT value's ``(idx << 6) | pw`` packed once per dataset, gathered
+    by code. Null/padding rows -> 0, which never wins a register max."""
+    def per_entry():
+        return hll_pack_pairs(dict_entry_hll_pairs(col))
+
+    return _gather_by_code(col, _entry_table(col, "hll_packed", per_entry, np.uint16))
 
 
 def dict_entry_hashes(col) -> np.ndarray:
@@ -257,9 +290,13 @@ class FeatureBuilder:
         # in `_columns_needed`, not here
         return sorted({s.column for s in self.specs.values() if s.column is not None})
 
-    def build(self, batch: Batch) -> Dict[str, np.ndarray]:
+    def build(self, batch: Batch, monitor=None) -> Dict[str, np.ndarray]:
+        """The features of one batch. ``monitor`` (a ``RunMonitor``) counts
+        the HLL features served from a dictionary's per-entry table
+        (``dict_hll_gathers``)."""
         features: Dict[str, np.ndarray] = {}
         pred_columns: Dict[str, np.ndarray] | None = None
+        dict_hll_gathers = 0
         for key, spec in self.specs.items():
             if spec.kind == "rows":
                 features[key] = batch.row_mask
@@ -311,7 +348,12 @@ class FeatureBuilder:
                 else:
                     features[key] = hash_column(col.values, col.mask, col.kind)
             elif spec.kind == "hll":
-                features[key] = _hll_packed(batch.column(spec.column))
+                col = batch.column(spec.column)
+                if _is_string_dict(col):
+                    features[key] = dict_hll_packed(col)
+                    dict_hll_gathers += 1
+                else:
+                    features[key] = _hll_packed(col)
             elif spec.kind == "codes":
                 col = batch.column(spec.column)
                 if col.codes is None:
@@ -327,6 +369,8 @@ class FeatureBuilder:
                 features[key] = mask & batch.row_mask
             else:
                 raise ValueError(f"unknown feature kind {spec.kind}")
+        if monitor is not None and dict_hll_gathers:
+            monitor.bump("dict_hll_gathers", dict_hll_gathers)
         return features
 
 

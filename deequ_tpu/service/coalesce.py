@@ -1227,7 +1227,7 @@ class FoldCoalescer:
                         f.bucket, columns=f.plan.columns
                     ):
                         break
-                    feats = f.plan.builder().build(batch)
+                    feats = f.plan.builder().build(batch, f.monitor)
                 prepped.append((f, data, pending_contract, feats))
             except BaseException as exc:
                 self._complete(f, error=exc)

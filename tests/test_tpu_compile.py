@@ -79,15 +79,18 @@ def _features(analyzers, batch, sharding_for):
     return out
 
 
-def _compile_update(prog, features, state_sharding):
-    """Compile a PackedScanProgram's fused update for the described chip."""
+def _compile_update(prog, features, state_sharding, slot_keys=None):
+    """Compile a PackedScanProgram's fused update for the described chip.
+    ``slot_keys``: the battery's own per-slot feature keys, where ``prog``
+    is a shared bundle program traced with another battery's columns."""
     import jax
 
     carry = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=state_sharding),
         jax.eval_shape(prog.init_carry),
     )
-    slots = tuple(tuple(features[k] for k in keys) for keys in prog._spec_keys)
+    keys_of = prog._spec_keys if slot_keys is None else slot_keys
+    slots = tuple(tuple(features[k] for k in keys) for keys in keys_of)
     return prog._update.lower(carry, slots).compile()
 
 
@@ -100,7 +103,7 @@ def test_scan_bundle_at_batch_2pow20(one_chip):
     assert len(prog._programs) == 1  # one signature bundle
     compiled = _compile_update(
         prog._programs[0], _features(battery, BATCH, lambda s: one_chip),
-        one_chip,
+        one_chip, prog._slot_keys[0],
     )
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
