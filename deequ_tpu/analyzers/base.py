@@ -535,6 +535,13 @@ class ScanShareableAnalyzer(Analyzer[S, M]):
         or at metric time, so the default is empty."""
         return ()
 
+    def row_split_leaves(self) -> Tuple[int, ...]:
+        """Flat indices of state leaves whose leading axis is split over a
+        mesh's row axis, one block per device, so each device folds into
+        its own block only; every other leaf is replicated. Default:
+        none."""
+        return ()
+
     @abc.abstractmethod
     def init_state(self) -> S:
         ...

@@ -21,8 +21,10 @@ from __future__ import annotations
 import abc
 import math
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 import pandas as pd
 
@@ -43,6 +45,7 @@ from ..exceptions import (
     IllegalAnalyzerParameterException,
     wrap_if_necessary,
 )
+from ..utils import BoundedLRU
 from .base import Analyzer, Preconditions, ScanShareableAnalyzer
 
 COUNT_COL = "count"
@@ -735,96 +738,182 @@ class DeviceFrequencyScan(ScanShareableAnalyzer):
         )
 
 
-def _u64_value_counts(keys: np.ndarray, weights):
-    """Exact (unique key -> summed weight) over u64 hash keys: the
-    cache-partitioned native kernel when built (hundreds of ms for 25M
-    keys), a numpy argsort + reduceat otherwise. ``weights=None`` counts
-    each key once (the resident-buffer fast path — no materialized ones
-    array); explicit weights must be positive (the native kernel treats
-    zero as the empty-slot marker)."""
-    if len(keys) == 0:
-        return keys.astype(np.uint64), np.zeros(0, dtype=np.int64)
-    from ..native import native_u64_value_counts
+class FrequencySummary:
+    """The drained host view of a device frequency table
+    (:class:`~..analyzers.states.FrequencyTableState`): the count multiset
+    reduced on the device to what the scalar frequency reductions
+    (Uniqueness, Distinctness, UniqueValueRatio, CountDistinct, Entropy)
+    read, through the same ``stream_summary``/``num_distinct``/``is_empty``
+    protocol as :class:`FrequenciesAndNumRows`, so the analyzers' metric
+    code is state-type agnostic. sum(c ln c) comes from the count-of-counts
+    histogram through the canonical reduction, bit-identical to the host
+    group-by's. Key-reading consumers (Histogram bins, MutualInformation
+    marginals) never receive one: runner eligibility keeps those on the
+    dictionary or host paths.
 
-    if native_u64_value_counts is not None:
-        out = native_u64_value_counts(keys, weights)
-        if out is not None:
-            return out
-    order = np.argsort(keys, kind="stable")
-    k = keys[order]
-    w = (
-        np.ones(len(k), dtype=np.int64)
-        if weights is None
-        else weights[order].astype(np.int64)
+    ``lost_rows > 0`` marks a table whose compactions dropped groups: its
+    counts are incomplete and the runner re-runs the set through the host
+    accumulator."""
+
+    __slots__ = (
+        "num_rows", "group_columns", "lost_groups", "lost_rows", "_summary",
     )
-    starts = np.flatnonzero(np.concatenate([[True], k[1:] != k[:-1]]))
-    return k[starts], np.add.reduceat(w, starts)
-
-
-class HashedFrequencies:
-    """Exact count multiset keyed by 64-bit GROUP-KEY HASHES — the drained
-    host view of a :class:`~..analyzers.states.FrequencyTableState`.
-
-    The scalar frequency reductions (Uniqueness, Distinctness,
-    UniqueValueRatio, CountDistinct, Entropy) are pure functions of the
-    count multiset plus ``num_rows``; hashing the keys loses nothing for
-    them. Key-READING consumers (Histogram bins, MutualInformation
-    marginals) never receive one — runner eligibility keeps those on the
-    dictionary or host paths. Reads through the same
-    ``stream_summary``/``num_distinct``/``is_empty`` protocol as
-    :class:`FrequenciesAndNumRows`, so the analyzers' metric code is
-    state-type agnostic."""
-
-    __slots__ = ("keys", "counts", "num_rows", "group_columns", "_summary")
 
     def __init__(
-        self,
-        keys: np.ndarray,
-        counts: np.ndarray,
-        num_rows: int,
-        group_columns: Sequence[str],
+        self, groups: int, singletons: int, rows: int,
+        hist_values: np.ndarray, hist_mults: np.ndarray, num_rows: int,
+        group_columns: Sequence[str], lost_groups: int = 0,
+        lost_rows: int = 0,
     ):
-        self.keys = np.asarray(keys, dtype=np.uint64)
-        self.counts = np.asarray(counts, dtype=np.int64)
         self.num_rows = int(num_rows)
         self.group_columns = list(group_columns)
-        self._summary: Optional[Tuple[int, int, int, float]] = None
+        self.lost_groups = int(lost_groups)
+        self.lost_rows = int(lost_rows)
+        self._summary: Tuple[int, int, int, float] = (
+            int(groups), int(singletons), int(rows),
+            _reduce_count_histogram(
+                np.asarray(hist_values, np.int64),
+                np.asarray(hist_mults, np.int64),
+            ),
+        )
 
     def num_distinct(self) -> int:
-        return len(self.counts)
+        return self._summary[0]
 
     def is_empty(self) -> bool:
-        return len(self.counts) == 0
+        return self._summary[0] == 0
 
     def stream_summary(self) -> Tuple[int, int, int, float]:
         """(num_distinct, singleton_count, sum(count), sum(count*ln(count)))
-        — the same cached quadruple FrequenciesAndNumRows serves."""
-        if self._summary is None:
-            self._summary = (
-                len(self.counts),
-                int((self.counts == 1).sum()),
-                int(self.counts.sum()),
-                _sum_c_ln_c(self.counts),
-            )
+        — the quadruple FrequenciesAndNumRows serves."""
         return self._summary
 
     def close(self) -> None:  # protocol parity with FrequenciesAndNumRows
         pass
 
-    def sum(self, other: "HashedFrequencies") -> "HashedFrequencies":
-        if not isinstance(other, HashedFrequencies):
-            raise TypeError(
-                f"cannot merge a hashed frequency state with "
-                f"{type(other).__name__}: hashed device-frequency states "
-                "and value-keyed host states never mix"
+    def sum(self, other) -> "FrequencySummary":
+        raise TypeError(
+            f"cannot merge a drained device-frequency summary with "
+            f"{type(other).__name__}: it holds no keys, and hashed "
+            "device-frequency states and value-keyed host states never mix"
+        )
+
+
+#: the drain's scalar outputs, in order (one int64 vector, one transfer)
+_DRAIN_SCALARS = (
+    "groups", "singletons", "rows", "distinct_counts", "busiest_shard_rows",
+    "sent_rows", "lost_groups", "lost_rows", "num_rows",
+)
+
+#: compiled drain programs keyed by (mesh devices when sharded, shards,
+#: resident, histogram slots)
+_DRAIN_PROGRAMS = BoundedLRU(64)
+
+
+def _drain_program(mesh, shards: int, resident: bool, hist_slots: int):
+    """The jitted device drain of one table layout (``jit_freq_drain_summary``
+    in a device trace): the buffered keys (and, when the table compacts,
+    its entries) sorted and run-length counted into the summary
+    :func:`..ops.count_summary` gives. With ``shards > 1`` it runs on each
+    device of ``mesh`` over its own buffer rows: one exchange
+    (:func:`..parallel.exchange_by_key_range`) first brings every key to
+    the device that owns its range, so the devices' groups are disjoint
+    and their summaries add."""
+    import jax.numpy as jnp
+
+    from ..ops import count_summary, freq_compact
+    from ..ops.hashing import FREQ_KEY_SENTINEL
+
+    sharded = shards > 1
+    key = (
+        tuple(mesh.devices.flat) if sharded else None, shards, resident,
+        hist_slots,
+    )
+    program = _DRAIN_PROGRAMS.get(key)
+    if program is not None:
+        return program
+    from ..parallel import ROW_AXIS
+
+    def summarize(st):
+        sent = jnp.uint64(FREQ_KEY_SENTINEL)
+        with jax.named_scope("freq_partition"):
+            keys = st.buffered_keys(shards).reshape(-1)
+            if resident:
+                # the resident trace never compacts: its table is empty
+                keys = jnp.sort(keys)
+                if sharded:
+                    from ..parallel import exchange_by_key_range
+
+                    keys = jnp.sort(exchange_by_key_range(keys, sent))
+                weights = (keys != sent).astype(jnp.int64)
+            else:
+                keys, weights = jax.lax.sort(
+                    (
+                        jnp.concatenate([st.sorted_keys, keys]),
+                        jnp.concatenate([
+                            st.sorted_counts,
+                            (keys != sent).astype(jnp.int64),
+                        ]),
+                    ),
+                    num_keys=1,
+                )
+        with jax.named_scope("freq_summary"):
+            # a resident buffer holds fewer than 2^31 keys, so each count
+            # fits 32 bits
+            groups, singles, rows, values, mults, distinct = count_summary(
+                keys, weights, hist_slots, sent,
+                jnp.int32 if resident else jnp.int64,
             )
-        keys, counts = _u64_value_counts(
-            np.concatenate([self.keys, other.keys]),
-            np.concatenate([self.counts, other.counts]),
+            busiest = rows
+            if sharded:
+                groups, singles, rows = (
+                    jax.lax.psum(x, ROW_AXIS) for x in (groups, singles, rows)
+                )
+                # an s64 max all-reduce does not lower on TPU
+                busiest = jnp.max(jax.lax.all_gather(busiest, ROW_AXIS))
+                # the devices' histograms, merged by count value (a split
+                # table is resident: 32-bit keys)
+                top = jnp.int32(np.iinfo(np.int32).max)
+                mults = jax.lax.all_gather(mults, ROW_AXIS).reshape(-1)
+                values = jax.lax.all_gather(values, ROW_AXIS).reshape(-1)
+                values, mults, distinct, _, _ = freq_compact(
+                    jnp.where(mults > 0, values.astype(jnp.int32), top),
+                    mults, hist_slots, top,
+                )
+            scalars = jnp.stack([
+                x.astype(jnp.int64) for x in (
+                    groups, singles, rows, distinct, busiest, st.sent_rows,
+                    st.lost_groups, st.lost_rows, st.num_rows,
+                )
+            ])
+            return scalars, jnp.stack([values.astype(jnp.int64), mults])
+
+    if sharded:
+        from jax.sharding import PartitionSpec as P
+
+        from ..parallel import _shard_map
+        from .states import FrequencyTableState
+
+        specs = FrequencyTableState(
+            P(), P(), P(), P(ROW_AXIS, None), P(), P(), P(), P(), P()
         )
-        return HashedFrequencies(
-            keys, counts, self.num_rows + other.num_rows, self.group_columns
+        body = _shard_map(
+            summarize, mesh=mesh, in_specs=(specs,), out_specs=(P(), P())
         )
+    else:
+        body = summarize
+
+    def freq_drain_summary(state):
+        return body(state)
+
+    program = jax.jit(freq_drain_summary)
+    _DRAIN_PROGRAMS[key] = program
+    return program
+
+
+@partial(jax.jit, static_argnums=1)
+def _hist_prefix(hist, width: int):
+    return hist[:, :width]
 
 
 @dataclass(frozen=True)
@@ -847,6 +936,12 @@ class DeviceFrequencyTableScan(ScanShareableAnalyzer):
     fixed-shape table only when it would overflow, keeping the trace
     shape-static and signature-bundleable.
 
+    On a mesh of ``shards`` devices the buffer has one row per device and
+    each device appends only its own rows' keys (``row_split_leaves``); at
+    the pass end :meth:`drain` partitions the keys over the devices by
+    key range and counts them where they land, so the table is split over
+    the chips from the first batch to the summary.
+
     Tiering: groups beyond the table's ``slots`` capacity are dropped with
     EXACT loss accounting (``lost_rows``); the runner detects a lossy drain
     and re-runs just those grouping sets through the host accumulator
@@ -854,8 +949,8 @@ class DeviceFrequencyTableScan(ScanShareableAnalyzer):
     default path).
 
     Runner-internal, like :class:`DeviceFrequencyScan`: the runner
-    instantiates it for eligible sets and drains the state into a
-    :class:`HashedFrequencies` every member analyzer reads."""
+    instantiates it for eligible sets, and the engine drains the state
+    into a :class:`FrequencySummary` every member analyzer reads."""
 
     columns: Tuple[str, ...] = ()
     #: per-column key derivation, positionally parallel to ``columns``:
@@ -864,12 +959,15 @@ class DeviceFrequencyTableScan(ScanShareableAnalyzer):
     #: the traced update.
     column_kinds: Tuple[str, ...] = ()
     slots: int = 0
+    #: key buffer entries per shard
     buffer_entries: int = 0
     #: RESIDENT mode: the planner proved ``buffer_entries`` covers every
     #: padded batch of the run, so the update emits NO compaction cond —
     #: the hot path is a pure donated-carry append (frozen identity: it
     #: changes the traced program)
     resident: bool = False
+    #: devices the key buffer is split over (only a resident buffer is)
+    shards: int = 1
     name: str = field(default="DeviceFrequencyTableScan", init=False)
 
     supports_host_partial = False  # raw keys must stream to the device;
@@ -886,6 +984,17 @@ class DeviceFrequencyTableScan(ScanShareableAnalyzer):
         # happen to match a cached resident program would execute the
         # cond-free trace and overflow the buffer silently.
         return (self.resident,)
+
+    def row_split_leaves(self) -> Tuple[int, ...]:
+        if self.shards == 1:
+            return ()
+        from dataclasses import fields
+
+        from .states import FrequencyTableState
+
+        return (
+            [f.name for f in fields(FrequencyTableState)].index("buf"),
+        )
 
     def feature_specs(self):
         from .base import (
@@ -906,7 +1015,9 @@ class DeviceFrequencyTableScan(ScanShareableAnalyzer):
     def init_state(self):
         from .states import FrequencyTableState
 
-        return FrequencyTableState.init(self.slots, self.buffer_entries)
+        return FrequencyTableState.init(
+            self.slots, self.buffer_entries, self.shards
+        )
 
     def update(self, state, features):
         import jax.numpy as jnp
@@ -953,46 +1064,63 @@ class DeviceFrequencyTableScan(ScanShareableAnalyzer):
     def merge(self, a, b):
         return a.merge(b)
 
-    def drain(self, state) -> Optional[HashedFrequencies]:
-        """Fetched (host numpy) state -> exact HashedFrequencies, or None
-        when compactions dropped groups (``lost_rows > 0``) — the runner
-        then re-runs this set through the host accumulator tier."""
-        from ..ops.hashing import FREQ_KEY_SENTINEL
+    def hist_slots(self) -> int:
+        """Slots of the drain's count-of-counts histogram: enough for every
+        distinct count value. ``d`` distinct counts need d(d+1)/2 rows, so
+        d < sqrt(2 rows), and a resident buffer holds at most one row per
+        entry; a compacting table's counts are bounded only by its
+        entries."""
+        entries = self.shards * self.buffer_entries
+        if self.resident:
+            return _next_pow2(math.isqrt(2 * entries))
+        return _next_pow2(self.slots + entries)
 
-        if int(state.lost_rows) > 0:
-            return None
-        sent_key = np.uint64(FREQ_KEY_SENTINEL)
-        buf = np.asarray(state.buf)[: int(state.buf_fill)]  # contiguous view
-        if int(state.n_table) == 0:
-            # resident fast path: the whole run lives in the buffer — feed
-            # the view straight to the aggregation (no concat copy, no
-            # 25M-row sentinel pre-filter; the sentinel aggregates into ONE
-            # output entry dropped below)
-            keys, counts = _u64_value_counts(buf, None)
-        else:
-            tcounts = np.asarray(state.sorted_counts)
-            nz = tcounts > 0
-            tkeys = np.asarray(state.sorted_keys)[nz]
-            tcounts = tcounts[nz]
-            keys, counts = _u64_value_counts(
-                np.concatenate([tkeys, buf]),
-                np.concatenate([tcounts, np.ones(len(buf), dtype=np.int64)]),
-            )
-        # drop the aggregated sentinel group (masked/null rows, structural
-        # batch padding, and valid rows whose key collided with the
-        # sentinel — the last counted exactly in sent_rows and restored as
-        # their own group here)
-        at = np.flatnonzero(keys == sent_key)
-        if len(at):
-            keys = np.delete(keys, at)
-            counts = np.delete(counts, at)
-        sent = int(state.sent_rows)
+    def drain(self, state, mesh=None):
+        """The device half of the drain: dispatch the summary program over
+        the pass-end device state (a mesh run's state stays split over
+        ``mesh``). Returns device arrays for :meth:`fetch_summary`."""
+        return _drain_program(
+            mesh, self.shards, self.resident, self.hist_slots()
+        )(state)
+
+    def fetch_summary(self, drained) -> Tuple["FrequencySummary", dict]:
+        """The host half: the summary's scalars in one transfer, then the
+        used part of the count-of-counts histogram in another. Rows whose
+        real key collided with the sentinel (``sent_rows``) come back here
+        as their own group. Returns the summary and what the drain read
+        and moved: ``keys`` (valid keys counted, all devices),
+        ``busiest_shard_keys`` and ``fetch_bytes``."""
+        scalars, hist = drained
+        host = np.asarray(scalars)
+        s = dict(zip(_DRAIN_SCALARS, (int(v) for v in host)))
+        fetched = host.nbytes
+        distinct = s["distinct_counts"]
+        values = np.zeros(0, np.int64)
+        mults = np.zeros(0, np.int64)
+        if distinct:
+            part = np.asarray(_hist_prefix(hist, _next_pow2(distinct)))
+            fetched += part.nbytes
+            values, mults = part[0, :distinct], part[1, :distinct]
+        groups, singles, rows = s["groups"], s["singletons"], s["rows"]
+        sent = s["sent_rows"]
         if sent:
-            keys = np.concatenate([keys, [sent_key]])
-            counts = np.concatenate([counts, [np.int64(sent)]])
-        return HashedFrequencies(
-            keys, counts, int(state.num_rows), list(self.columns)
+            groups, singles, rows = groups + 1, singles + (sent == 1), rows + sent
+            at = int(np.searchsorted(values, sent))
+            if at < len(values) and values[at] == sent:
+                mults = mults.copy()
+                mults[at] += 1
+            else:
+                values = np.insert(values, at, sent)
+                mults = np.insert(mults, at, 1)
+        summary = FrequencySummary(
+            groups, singles, rows, values, mults, s["num_rows"],
+            self.columns, s["lost_groups"], s["lost_rows"],
         )
+        stats = {
+            "keys": s["rows"], "busiest_shard_keys": s["busiest_shard_rows"],
+            "fetch_bytes": fetched,
+        }
+        return summary, stats
 
     def compute_metric_from(self, state):  # pragma: no cover - runner-internal
         raise NotImplementedError(
@@ -1095,7 +1223,7 @@ def probably_low_cardinality(
 
 def plan_table_scan(
     schema, columns: Sequence[str], num_rows: int, batch_rows: int,
-    sharded: bool = False,
+    shards: int = 1,
 ) -> Optional[DeviceFrequencyTableScan]:
     """Size a DeviceFrequencyTableScan for one grouping set, or None when a
     column's kind cannot derive a 64-bit key.
@@ -1106,15 +1234,18 @@ def plan_table_scan(
     RESIDENT: per-row keys append at memcpy speed with NO compaction cond
     in the trace, and the single drain-time aggregation is exact for ANY
     cardinality up to the buffer — the fast path the bench grouping stage
-    measures. An UNSHARDED resident plan gets a minimal table (the trace
-    never compacts into it and drain ignores it, so full slots would be
-    ~67MB of dead HBM + fetch transfer per set); sharded resident states
-    DO compact into the table at collective merge, so they keep full
-    capacity. Larger runs get the conditional-compaction trace: ``slots``
-    is the configured table capacity capped at the row count (distinct <=
-    rows, so a table with slots >= rows can NEVER overflow) and the
-    buffer covers at least one padded batch so the compaction sort
-    amortizes."""
+    measures. On a mesh of ``shards`` devices (``batch_rows`` a multiple of
+    it) a resident buffer splits over them, one row of a ``1/shards`` share
+    per device. The cap holds for the rows taken together: the drain's
+    exchange sends each device a block as long as a whole row from every
+    device, so each device receives and sorts ``shards`` rows, which must
+    fit what one device drains. A resident plan gets a minimal table:
+    the trace never compacts into it, so full slots would be ~67MB of dead
+    HBM per set. Larger runs get the conditional-compaction trace,
+    replicated on a mesh: ``slots`` is the configured table capacity
+    capped at the row count (distinct <= rows, so a table with slots >=
+    rows can NEVER overflow) and the buffer covers at least one padded
+    batch so the compaction sort amortizes."""
     from ..data import ColumnKind
 
     kinds: List[str] = []
@@ -1138,10 +1269,11 @@ def plan_table_scan(
     # against the rounded cap so a non-pow2 setting admits exactly the
     # runs its allocated (pow2) buffer can hold
     buffer_cap = _next_pow2(freq_buffer_entries())
-    if padded_rows <= buffer_cap:
+    per_shard = _next_pow2(max(padded_rows, batch_rows) // shards)
+    if shards * per_shard <= buffer_cap:
         return DeviceFrequencyTableScan(
-            tuple(columns), tuple(kinds), slots if sharded else 8,
-            _next_pow2(max(padded_rows, batch_rows)), resident=True,
+            tuple(columns), tuple(kinds), 8, per_shard,
+            resident=True, shards=shards,
         )
     buffer_entries = _next_pow2(
         max(batch_rows, min(slots, 1 << 20, buffer_cap))

@@ -80,13 +80,19 @@ class FrequencyTableState:
     Merging (cross-batch, cross-device ``collective_merge_states``,
     cross-run) is the same compaction over both operands' tables and
     buffers — the frequency analog of the reference's outer-join merge
-    (`GroupingAnalyzers.scala:128-148`) without ever leaving the device."""
+    (`GroupingAnalyzers.scala:128-148`) without ever leaving the device.
+
+    The buffer has one row per shard: on a mesh each device holds one row
+    and appends only its own rows' keys to it (every batch splits evenly
+    over the devices, so each row holds ``buf_fill // shards`` keys). One
+    row is the unsharded layout."""
 
     sorted_keys: jnp.ndarray    # uint64[slots], ascending, sentinel-padded
     sorted_counts: jnp.ndarray  # int64[slots]
     n_table: jnp.ndarray        # int64: occupied table entries
-    buf: jnp.ndarray            # uint64[buffer_entries] raw per-row keys
-    buf_fill: jnp.ndarray       # int64: appended entries (rows incl. masked)
+    buf: jnp.ndarray            # uint64[shards, buffer_entries] raw keys
+    buf_fill: jnp.ndarray       # int64: appended entries, all rows (incl.
+    #   masked rows)
     sent_rows: jnp.ndarray      # int64: rows whose key collided w/ sentinel
     lost_groups: jnp.ndarray    # int64: groups dropped at compactions (an
     #   upper bound: a group re-appearing after a drop counts again)
@@ -95,19 +101,36 @@ class FrequencyTableState:
     num_rows: jnp.ndarray       # int64: ALL rows seen (grouping semantics)
 
     @staticmethod
-    def init(slots: int, buffer_entries: int) -> "FrequencyTableState":
+    def init(
+        slots: int, buffer_entries: int, shards: int = 1
+    ) -> "FrequencyTableState":
         from ..ops.hashing import FREQ_KEY_SENTINEL
 
         return FrequencyTableState(
             jnp.full(slots, FREQ_KEY_SENTINEL, dtype=jnp.uint64),
             jnp.zeros(slots, dtype=jnp.int64),
             jnp.zeros((), dtype=jnp.int64),
-            jnp.zeros(buffer_entries, dtype=jnp.uint64),
+            jnp.zeros((shards, buffer_entries), dtype=jnp.uint64),
             jnp.zeros((), dtype=jnp.int64),
             jnp.zeros((), dtype=jnp.int64),
             jnp.zeros((), dtype=jnp.int64),
             jnp.zeros((), dtype=jnp.int64),
             jnp.zeros((), dtype=jnp.int64),
+        )
+
+    def buffered_keys(self, shards: int = 0) -> jnp.ndarray:
+        """The buffer with its unfilled slots read as the sentinel (traced;
+        the buffer is zero-initialized and zero is a real key). ``shards``
+        is the number of rows of the whole buffer, where this is one
+        device's part of it; by default the rows held here."""
+        from ..ops.hashing import FREQ_KEY_SENTINEL
+
+        shards = shards or self.buf.shape[0]
+        per = self.buf.shape[1]
+        col = jnp.arange(per, dtype=jnp.int64)
+        return jnp.where(
+            col[None, :] < self.buf_fill // shards, self.buf,
+            jnp.uint64(FREQ_KEY_SENTINEL),
         )
 
     @jax.named_scope("freq_compact")
@@ -119,10 +142,8 @@ class FrequencyTableState:
         from ..ops.hashing import FREQ_KEY_SENTINEL
 
         sent = jnp.uint64(FREQ_KEY_SENTINEL)
-        cap = self.buf.shape[0]
         slots = self.sorted_keys.shape[0]
-        idx = jnp.arange(cap, dtype=jnp.int64)
-        bkeys = jnp.where(idx < self.buf_fill, self.buf, sent)
+        bkeys = self.buffered_keys().reshape(-1)
         bcounts = (bkeys != sent).astype(jnp.int64)
         out_keys, out_counts, n_raw, kept, total = freq_compact(
             jnp.concatenate([self.sorted_keys, bkeys]),
@@ -160,18 +181,27 @@ class FrequencyTableState:
         donated-carry append runs at memcpy speed (>250M rows/s). The
         conditional-compaction trace remains for runs whose rows exceed the
         buffer; its sort cost amortizes over ``buffer_entries / batch``
-        batches."""
+        batches.
+
+        With one buffer row per shard, row ``i`` takes the ``i``-th equal
+        part of the batch: on a mesh that is the part the ``i``-th device
+        holds, so the append stays on the device (no gather of keys)."""
         batch = keys.shape[0]
-        cap = self.buf.shape[0]
+        shards = self.buf.shape[0]
+        cap = self.buf.size
         if batch > cap:
             raise ValueError(
                 f"frequency-table buffer holds {cap} entries but the batch "
                 f"carries {batch} rows; size buffer_entries >= the padded "
                 "batch size (the runner guarantees this)"
             )
+        rows = keys.reshape(shards, batch // shards)
 
         def just_append(st: "FrequencyTableState") -> "FrequencyTableState":
-            buf = jax.lax.dynamic_update_slice(st.buf, keys, (st.buf_fill,))
+            buf = jax.lax.dynamic_update_slice(
+                st.buf, rows,
+                (jnp.zeros((), jnp.int64), st.buf_fill // shards),
+            )
             return st.replace(buf=buf, buf_fill=st.buf_fill + batch)
 
         if assume_fits:

@@ -26,7 +26,6 @@ native_block_kll_sample = None
 native_dict_masked_bincount = None
 native_block_kll_pick = None
 native_pattern_match = None
-native_u64_value_counts = None
 
 try:  # pragma: no cover - exercised when the native lib is built
     from .lib import (  # noqa: F401
@@ -42,7 +41,6 @@ try:  # pragma: no cover - exercised when the native lib is built
         native_hll_pack_strings,
         native_pattern_match,
         native_string_lengths,
-        native_u64_value_counts,
         native_xxhash64_strings,
     )
 except Exception:  # noqa: BLE001

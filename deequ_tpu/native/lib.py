@@ -67,10 +67,6 @@ _lib.pattern_match_batch.argtypes = [
     _u8p, _i64p, _u8p, ctypes.c_int64, ctypes.c_char_p, _u8p,
 ]
 _lib.pattern_match_batch.restype = ctypes.c_int
-_lib.u64_value_counts.argtypes = [
-    _u64p, _i64p, ctypes.c_int64, _u64p, _i64p,
-]
-_lib.u64_value_counts.restype = ctypes.c_int64
 
 
 def _arrow_layout(values):
@@ -327,31 +323,6 @@ def native_pattern_match(values, mask, pattern: str):
             m = compiled.search(text)
             result[i] = bool(m) and m.group(0) != ""
     return result
-
-
-def native_u64_value_counts(keys: np.ndarray, weights=None):
-    """(unique_keys u64[m], summed_weights i64[m]) over hashed group keys —
-    the cache-partitioned C aggregation the device frequency engine's host
-    drain uses (25M keys fold in a few hundred ms where np.unique pays a
-    full 2s sort). ``weights=None`` counts each key once; explicit weights
-    must be POSITIVE (zero weights are treated as absent — the empty-slot
-    marker of the open tables). Returns None on allocation failure (caller
-    falls back to the numpy sort path)."""
-    keys = np.ascontiguousarray(keys, dtype=np.uint64)
-    n = len(keys)
-    out_keys = np.empty(n, dtype=np.uint64)
-    out_weights = np.empty(n, dtype=np.int64)
-    wp = None
-    if weights is not None:
-        weights = np.ascontiguousarray(weights, dtype=np.int64)
-        wp = _ptr(weights, _i64p)
-    m = _lib.u64_value_counts(
-        _ptr(keys, _u64p), wp, ctypes.c_int64(n),
-        _ptr(out_keys, _u64p), _ptr(out_weights, _i64p),
-    )
-    if m < 0:
-        return None
-    return out_keys[:m].copy(), out_weights[:m].copy()
 
 
 def native_dict_masked_bincount(

@@ -691,216 +691,6 @@ void dict_masked_bincount(const int32_t* codes, const uint8_t* mask,
 }  // extern "C"
 
 // ---------------------------------------------------------------------------
-// u64_value_counts — exact (key -> summed weight) aggregation of hashed
-// group keys: the host-side drain of the device frequency engine (buffer
-// tail + table entries fold through this in one call). Keys are xxhash64
-// outputs (uniformly distributed), so a radix partition on the TOP bits
-// splits the input into runs whose open-addressing tables stay
-// cache-resident — a straight 2x-sized global table thrashes LLC above a
-// few million distinct keys (~100ns/probe); partitioned probing stays at
-// memory-bandwidth speeds. All three phases (histogram, scatter, probe)
-// parallelize over std::thread — the caller holds no GIL here.
-// weights == nullptr means all-ones. Returns the number of distinct keys
-// written to out_keys/out_weights (caller sizes both at n, the worst
-// case). -1 on allocation failure.
-// ---------------------------------------------------------------------------
-
-#include <thread>
-#include <vector>
-
-namespace {
-
-inline int64_t next_pow2_i64(int64_t v) {
-  int64_t p = 1;
-  while (p < v) p <<= 1;
-  return p;
-}
-
-// probe one partitioned run [lo, hi) into a zeroed table of tcap slots;
-// the slot seed re-mixes the key (Fibonacci multiply) rather than taking
-// raw key bits: engine keys are avalanched hashes, but low-entropy keys
-// from any other caller (or adversarial preimages of the public
-// splitmix64 mixer) would otherwise all seed one slot and turn linear
-// probing O(distinct^2). pw == nullptr counts each key once (the
-// all-ones fast path skips an entire 8-byte-per-key weight stream).
-// Emits at out positions starting at `at`; returns entries emitted.
-int64_t count_run(const uint64_t* pk, const int64_t* pw, int64_t lo,
-                  int64_t hi, uint64_t* tk, int64_t* tw, int64_t tcap,
-                  uint64_t* out_keys, int64_t* out_weights, int64_t at) {
-  uint64_t tmsk = (uint64_t)(tcap - 1);
-  std::memset(tw, 0, (size_t)tcap * 8);
-  for (int64_t i = lo; i < hi; ++i) {
-    uint64_t k = pk[i];
-    int64_t w = pw != nullptr ? pw[i] : 1;
-    uint64_t s = (k * 0x9E3779B97F4A7C15ULL >> 16) & tmsk;
-    while (true) {
-      if (tw[s] == 0) { tk[s] = k; tw[s] = w; break; }
-      if (tk[s] == k) { tw[s] += w; break; }
-      s = (s + 1) & tmsk;
-    }
-  }
-  int64_t m = 0;
-  for (int64_t s = 0; s < tcap; ++s) {
-    if (tw[s] != 0) {
-      out_keys[at + m] = tk[s];
-      out_weights[at + m] = tw[s];
-      ++m;
-    }
-  }
-  return m;
-}
-
-}  // namespace
-
-extern "C" {
-
-int64_t u64_value_counts(const uint64_t* keys, const int64_t* weights,
-                         int64_t n, uint64_t* out_keys, int64_t* out_weights) {
-  if (n <= 0) return 0;
-  // partition count keeping each partition's table ~L2-resident
-  int64_t parts = 1;
-  while (parts < (1 << 12) && n / parts > (1 << 14)) parts <<= 1;
-  int shift = 64;
-  for (int64_t p = parts; p > 1; p >>= 1) --shift;
-
-  if (parts == 1) {
-    int64_t cap = next_pow2_i64(2 * n);
-    uint64_t* tk = (uint64_t*)std::malloc((size_t)cap * 8);
-    int64_t* tw = (int64_t*)std::malloc((size_t)cap * 8);
-    if (tk == nullptr || tw == nullptr) {
-      std::free(tk); std::free(tw);
-      return -1;
-    }
-    // identity layout: the inputs ARE the single run
-    int64_t m = count_run(keys, weights, 0, n, tk, tw, cap,
-                          out_keys, out_weights, 0);
-    std::free(tk); std::free(tw);
-    return m;
-  }
-
-  unsigned hw = std::thread::hardware_concurrency();
-  int64_t T = hw == 0 ? 1 : (int64_t)(hw < 8 ? hw : 8);
-  if (T > n / (1 << 16)) T = n / (1 << 16) > 0 ? n / (1 << 16) : 1;
-
-  int64_t* hist = (int64_t*)std::calloc((size_t)(T * parts), 8);
-  int64_t* counts = (int64_t*)std::calloc((size_t)parts + 1, 8);
-  uint64_t* pk = (uint64_t*)std::malloc((size_t)n * 8);
-  int64_t* pw =
-      weights != nullptr ? (int64_t*)std::malloc((size_t)n * 8) : nullptr;
-  if (hist == nullptr || counts == nullptr || pk == nullptr ||
-      (weights != nullptr && pw == nullptr)) {
-    std::free(hist); std::free(counts); std::free(pk); std::free(pw);
-    return -1;
-  }
-  auto slice = [&](int64_t t) -> std::pair<int64_t, int64_t> {
-    return {n * t / T, n * (t + 1) / T};
-  };
-  // phase 1: per-slice histograms
-  {
-    std::vector<std::thread> threads;
-    for (int64_t t = 0; t < T; ++t) {
-      threads.emplace_back([&, t] {
-        auto [lo, hi] = slice(t);
-        int64_t* h = hist + t * parts;
-        for (int64_t i = lo; i < hi; ++i) ++h[keys[i] >> shift];
-      });
-    }
-    for (auto& th : threads) th.join();
-  }
-  // exclusive prefix: counts[p] = start of partition p; per-(thread,
-  // partition) cursors so slices scatter into disjoint ranges
-  for (int64_t p = 0; p < parts; ++p) {
-    int64_t total = 0;
-    for (int64_t t = 0; t < T; ++t) {
-      int64_t c = hist[t * parts + p];
-      hist[t * parts + p] = total;  // becomes the thread's local offset
-      total += c;
-    }
-    counts[p + 1] = counts[p] + total;
-  }
-  // phase 2: parallel scatter into partitioned order
-  {
-    std::vector<std::thread> threads;
-    for (int64_t t = 0; t < T; ++t) {
-      threads.emplace_back([&, t] {
-        auto [lo, hi] = slice(t);
-        int64_t* cur = hist + t * parts;
-        if (weights != nullptr) {
-          for (int64_t i = lo; i < hi; ++i) {
-            int64_t p = (int64_t)(keys[i] >> shift);
-            int64_t at = counts[p] + cur[p]++;
-            pk[at] = keys[i];
-            pw[at] = weights[i];
-          }
-        } else {
-          for (int64_t i = lo; i < hi; ++i) {
-            int64_t p = (int64_t)(keys[i] >> shift);
-            pk[counts[p] + cur[p]++] = keys[i];
-          }
-        }
-      });
-    }
-    for (auto& th : threads) th.join();
-  }
-  // phase 3: probe partitions in parallel (p % T == t assignment keeps the
-  // load uniform — the hash spreads keys evenly), each thread with one
-  // reusable table sized for the largest partition. Uniques land inside
-  // each partition's own input range (distinct <= run length), recorded in
-  // `emitted`, then compact single-threaded (<= 16 bytes per distinct).
-  int64_t max_part = 0;
-  for (int64_t p = 0; p < parts; ++p) {
-    int64_t len = counts[p + 1] - counts[p];
-    if (len > max_part) max_part = len;
-  }
-  int64_t cap = next_pow2_i64(2 * (max_part > 0 ? max_part : 1));
-  int64_t* emitted = (int64_t*)std::calloc((size_t)parts, 8);
-  bool failed = false;
-  if (emitted == nullptr) failed = true;
-  if (!failed) {
-    std::vector<std::thread> threads;
-    std::vector<int> alloc_failed((size_t)T, 0);
-    for (int64_t t = 0; t < T; ++t) {
-      threads.emplace_back([&, t] {
-        uint64_t* tk = (uint64_t*)std::malloc((size_t)cap * 8);
-        int64_t* tw = (int64_t*)std::malloc((size_t)cap * 8);
-        if (tk == nullptr || tw == nullptr) {
-          std::free(tk); std::free(tw);
-          alloc_failed[(size_t)t] = 1;
-          return;
-        }
-        for (int64_t p = t; p < parts; p += T) {
-          int64_t lo = counts[p], hi = counts[p + 1];
-          if (lo == hi) continue;
-          int64_t tcap = next_pow2_i64(2 * (hi - lo));
-          emitted[p] = count_run(pk, pw, lo, hi, tk, tw, tcap,
-                                 out_keys, out_weights, lo);
-        }
-        std::free(tk); std::free(tw);
-      });
-    }
-    for (auto& th : threads) th.join();
-    for (int64_t t = 0; t < T; ++t) failed = failed || alloc_failed[(size_t)t];
-  }
-  int64_t m = -1;
-  if (!failed) {
-    m = 0;
-    for (int64_t p = 0; p < parts; ++p) {
-      int64_t lo = counts[p], e = emitted[p];
-      if (e && lo != m) {
-        std::memmove(out_keys + m, out_keys + lo, (size_t)e * 8);
-        std::memmove(out_weights + m, out_weights + lo, (size_t)e * 8);
-      }
-      m += e;
-    }
-  }
-  std::free(hist); std::free(counts); std::free(pk); std::free(pw);
-  std::free(emitted);
-  return m;
-}
-
-}  // extern "C"
-
-// ---------------------------------------------------------------------------
 // pattern_match_batch — unanchored regex search per row over the Arrow
 // string buffers, GIL-free, via the system PCRE2 library (dlopen'd so the
 // build carries no header/link dependency). PCRE2 is Perl-compatible like

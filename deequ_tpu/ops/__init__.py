@@ -48,6 +48,19 @@ def _prefix_sum(x, block: int = 1024):
     return (inner + offsets[:, None]).reshape(-1)[:n]
 
 
+def _prefix_max(x, block: int = 1024):
+    """Inclusive running maximum of a non-negative 1-D integer array, in
+    the same two levels as :func:`_prefix_sum` and for the same reason."""
+    n = x.shape[0]
+    pad = (-n) % block
+    if pad:
+        x = jnp.concatenate([x, jnp.zeros(pad, x.dtype)])
+    inner = jax.lax.cummax(x.reshape(-1, block), axis=1)
+    before = jax.lax.cummax(inner[:, -1])
+    before = jnp.concatenate([jnp.zeros(1, x.dtype), before[:-1]])
+    return jnp.maximum(inner, before[:, None]).reshape(-1)[:n]
+
+
 def freq_compact(keys, counts, out_size: int, sentinel):
     """Sort-merge compaction of (key, count) pairs into at most ``out_size``
     sorted uniques — the device frequency engine's table maintenance, shared
@@ -94,3 +107,46 @@ def freq_compact(keys, counts, out_size: int, sentinel):
     total_rows = tot[n - 1]
     kept_rows = jnp.sum(out_counts)
     return out_keys, out_counts, n_unique, kept_rows, total_rows
+
+
+def count_summary(keys, weights, hist_slots: int, sentinel, count_dtype):
+    """The count multiset of sorted (key, weight) entries, reduced to what
+    every scalar frequency metric reads: ``(groups, singletons, rows,
+    hist_values, hist_mults, distinct_counts)``. Keys are ascending with
+    ``sentinel`` entries (weight 0) last; a group is a run of equal keys
+    and its count the sum of its weights.
+
+    The count-of-counts histogram (each distinct count value with the
+    number of groups that have it, ascending, ``distinct_counts`` of its
+    ``hist_slots`` entries used) is kept whole rather than reduced to
+    sum(c ln c) here: the host reduces it through the one canonical
+    function, so Entropy stays bit-identical across the device and host
+    paths. ``hist_slots`` must cover the distinct count values; ``d`` of
+    them need at least d(d+1)/2 rows. ``count_dtype`` holds every group's
+    count: int32 where the entries are fewer than 2^31 rows (32-bit sorts
+    and scans are native on the TPU). Scatter-free: prefix scans, one sort
+    of the counts and gathers."""
+    valid = keys != sentinel
+    same = keys[1:] == keys[:-1]
+    is_start = valid & jnp.concatenate([jnp.ones(1, bool), ~same])
+    is_end = valid & jnp.concatenate([~same, jnp.ones(1, bool)])
+    tot = _prefix_sum(weights)
+    before = _prefix_max(jnp.where(is_start, tot - weights, 0))
+    counts = jnp.where(is_end, tot - before, 0)
+    groups = jnp.sum(is_end, dtype=jnp.int64)
+    singletons = jnp.sum(counts == 1, dtype=jnp.int64)
+    # the entries that end no group read 0 and sort first
+    c = jnp.sort(counts.astype(count_dtype))
+    n = c.shape[0]
+    last = (c != 0) & jnp.concatenate([c[1:] != c[:-1], jnp.ones(1, bool)])
+    rank = _prefix_sum(last.astype(count_dtype))
+    distinct = rank[-1]
+    # the r-th distinct count's last entry, and the one before it
+    pos = jnp.searchsorted(
+        rank, jnp.arange(1, hist_slots + 1, dtype=rank.dtype), side="left"
+    )
+    prev = jnp.concatenate([(n - groups - 1)[None].astype(pos.dtype), pos[:-1]])
+    used = jnp.arange(hist_slots) < distinct
+    values = jnp.where(used, c[jnp.clip(pos, 0, n - 1)], 0).astype(jnp.int64)
+    mults = jnp.where(used, pos - prev, 0).astype(jnp.int64)
+    return groups, singletons, tot[-1], values, mults, distinct.astype(jnp.int64)

@@ -155,7 +155,7 @@ def splitmix64_jnp(v):
     has ZERO collisions, so the device frequency table's count multiset
     equals the host group-by's exactly, not just overwhelmingly-probably —
     and the avalanche spreads sequential ids uniformly, which keeps the
-    host drain's radix partitions (native ``u64_value_counts``) balanced."""
+    drain's key ranges (one per device on a mesh) balanced."""
     import jax.numpy as jnp
 
     v = v ^ (v >> jnp.uint64(30))

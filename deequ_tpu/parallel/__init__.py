@@ -15,6 +15,10 @@ with algebraic state merge (Spark partial aggregation + shuffle;
    `KLLRunner.treeReduce` analog (reference `analyzers/runners/
    KLLRunner.scala:104-112`) for states whose merge is not a plain ``psum``
    (HLL register max, KLL level concat + compaction).
+3. **Exchange by key range** (`exchange_by_key_range`): inside a
+   shard_map program, every device sends each of its sorted 64-bit keys to
+   the device that owns the key's range, in one ``all_to_all`` — the
+   shuffle behind a frequency table split over the devices.
 
 Cross-host: the same code runs under multi-host jax (`jax.distributed`);
 mesh axes spanning hosts make the collectives ride DCN. States serialize to
@@ -140,6 +144,51 @@ def sharded_update(analyzers: Sequence[Any], mesh: Mesh):
         out_shardings=replicated(mesh),
         donate_argnums=0,
     )
+
+
+def key_range_starts(n: int) -> np.ndarray:
+    """The first key of each device's range but the first: device ``j`` of
+    ``n`` owns the 64-bit keys ``k`` with ``floor(k * n / 2^64) == j``,
+    i.e. ``k >= ceil(j * 2^64 / n)``. Keys are mixed hashes, so the ranges
+    split them evenly; the owner is a function of the key alone, so all
+    copies of a key meet on one device."""
+    return np.array([-(-(j << 64) // n) for j in range(1, n)], np.uint64)
+
+
+def exchange_by_key_range(keys, sentinel, axis: str = ROW_AXIS):
+    """Send every key to the device that owns its range (traced, inside a
+    shard_map over ``axis``). ``keys`` is this device's keys in ascending
+    order with ``sentinel`` entries last; they are not sent. Because the
+    keys are sorted and the owner rises with the key, each destination's
+    keys are one slice of them. Each slice goes out in a block as long as
+    all of ``keys``, so no skew, not even every key owned by one device,
+    can overflow it. Returns what this device received: one sorted,
+    sentinel-padded run from each device, ``n * len(keys)`` entries, so
+    the caller bounds ``n * len(keys)`` to what one device can sort
+    (:func:`~..analyzers.grouping.plan_table_scan` does)."""
+    n = jax.lax.axis_size(axis)
+    m = keys.shape[0]
+    valid = jnp.sum(keys != sentinel, dtype=jnp.int32)
+    starts = jnp.concatenate([
+        jnp.zeros(1, jnp.int32),
+        jnp.minimum(
+            jnp.searchsorted(keys, key_range_starts(n), side="left")
+            .astype(jnp.int32),
+            valid,
+        ),
+        valid[None],
+    ])
+    padded = jnp.concatenate([keys, jnp.full(m, sentinel, keys.dtype)])
+    col = jnp.arange(m, dtype=jnp.int32)
+    blocks = jnp.stack([
+        jnp.where(
+            col < starts[j + 1] - starts[j],
+            jax.lax.dynamic_slice(padded, (starts[j],), (m,)),
+            sentinel,
+        )
+        for j in range(n)
+    ])
+    return jax.lax.all_to_all(blocks, axis, 0, 0, tiled=True).reshape(-1)
 
 
 _SHARDED_INGEST_CACHE: dict = {}

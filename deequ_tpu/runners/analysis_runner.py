@@ -275,10 +275,12 @@ class AnalysisRunner:
                 and _device_tier_expected(scanning, placement)
             ):
                 batch_rows = _ebs(data, batch_size)
+                shards = 1
                 if sharding is not None:
                     from ..parallel import mesh_batch_quantum
 
-                    q = mesh_batch_quantum(int(sharding.devices.size))
+                    shards = int(sharding.devices.size)
+                    q = mesh_batch_quantum(shards)
                     batch_rows = ((batch_rows + q - 1) // q) * q
                 for cols, members in grouping_sets.items():
                     if cols in device_freq:
@@ -294,8 +296,7 @@ class AnalysisRunner:
                         # pre-engine routing for confidently-small sets
                         continue
                     scan = plan_table_scan(
-                        schema, cols, int(data.num_rows), batch_rows,
-                        sharded=sharding is not None,
+                        schema, cols, int(data.num_rows), batch_rows, shards
                     )
                     if scan is not None:
                         table_freq[cols] = scan
@@ -381,34 +382,31 @@ class AnalysisRunner:
                 placement=placement, sharding=sharding,
             )
 
-            # drain the device frequency tables. A set whose table
-            # overflowed (compactions dropped groups — drain returns None)
-            # or whose scan degraded re-runs through the host accumulator
-            # in ONE dedicated last-resort pass; _SpillStore sits below
-            # that, exactly the old default path, now reached only when
-            # the device tiers are exhausted.
+            # the engine drained the device frequency tables into
+            # summaries. A set whose table overflowed (compactions dropped
+            # groups) or whose scan degraded re-runs through the host
+            # accumulator in ONE dedicated last-resort pass; _SpillStore
+            # sits below that, exactly the old default path, now reached
+            # only when the device tiers are exhausted.
             table_shared: Dict[Tuple[str, ...], Any] = {}
             fallback_states: Dict[Any, Any] = {}
             fallback_errors: Dict[Any, BaseException] = {}
             fallback_sets: List[Tuple[str, ...]] = []
             fallback_losses: List[str] = []
-            if table_freq:
-                with run_monitor.timed("freq_drain"):
-                    for cols, scan in table_freq.items():
-                        state = outcome.states.get(scan)
-                        drained = None if state is None else scan.drain(state)
-                        if drained is None:
-                            fallback_sets.append(cols)
-                            if state is not None:
-                                run_monitor.bump("freq_overflow_fallbacks")
-                                fallback_losses.append(
-                                    f"{cols}: ~{int(state.lost_groups)} groups / "
-                                    f"{int(state.lost_rows)} rows dropped"
-                                )
-                            else:
-                                fallback_losses.append(f"{cols}: pass degraded")
-                        else:
-                            table_shared[cols] = drained
+            for cols, scan in table_freq.items():
+                summary = outcome.states.get(scan)
+                if summary is None:
+                    fallback_sets.append(cols)
+                    fallback_losses.append(f"{cols}: pass degraded")
+                elif summary.lost_rows:
+                    fallback_sets.append(cols)
+                    run_monitor.bump("freq_overflow_fallbacks")
+                    fallback_losses.append(
+                        f"{cols}: ~{summary.lost_groups} groups / "
+                        f"{summary.lost_rows} rows dropped"
+                    )
+                else:
+                    table_shared[cols] = summary
             if fallback_sets:
                 import logging
 

@@ -29,7 +29,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..analyzers.base import ScanShareableAnalyzer
-from ..analyzers.grouping import FrequenciesAndNumRows, GroupingAnalyzer
+from ..analyzers.grouping import (
+    DeviceFrequencyTableScan,
+    FrequenciesAndNumRows,
+    GroupingAnalyzer,
+)
 from ..config import DEFAULT_BATCH_SIZE
 from ..data import Dataset
 from ..observability import trace as _trace
@@ -116,6 +120,15 @@ class RunMonitor:
     #: (column, batch) HLL features served from a dictionary column's
     #: per-entry packed table instead of hashed per row
     dict_hll_gathers: int = 0
+    #: device frequency tables drained to a summary on the device
+    freq_device_summaries: int = 0
+    #: valid keys those drains counted, all devices
+    freq_keys_drained: int = 0
+    #: bytes those drains moved to the host
+    freq_drain_fetch_bytes: int = 0
+    #: the most keys one device counted in a drain (after the exchange by
+    #: key range on a mesh): the balance of the split table
+    freq_shard_max_keys: int = 0
     #: mesh shards (devices/processes) declared lost mid-pass — dead
     #: collectives, injected mesh_loss faults, heartbeat-declared stalls
     shard_losses: int = 0
@@ -176,6 +189,10 @@ class RunMonitor:
         self.device_freq_sets = 0
         self.freq_overflow_fallbacks = 0
         self.dict_hll_gathers = 0
+        self.freq_device_summaries = 0
+        self.freq_keys_drained = 0
+        self.freq_drain_fetch_bytes = 0
+        self.freq_shard_max_keys = 0
         self.shard_losses = 0
         self.mesh_reshards = 0
         self.salvaged_states = 0
@@ -201,13 +218,17 @@ class RunMonitor:
                 "checkpoint_saves", "corrupt_quarantined", "stalls",
                 "device_stalls", "device_freq_sets",
                 "freq_overflow_fallbacks", "dict_hll_gathers",
-                "shard_losses", "mesh_reshards",
+                "freq_device_summaries", "freq_keys_drained",
+                "freq_drain_fetch_bytes", "shard_losses", "mesh_reshards",
                 "salvaged_states", "fast_path_folds", "coalesced_folds",
                 "fleet_mesh_folds", "cost_probes", "partitions_scanned",
                 "partitions_reused", "partitions_invalidated",
                 "partitions_dropped", "partitions_rolled_up",
             ):
                 setattr(self, name, getattr(self, name) + getattr(other, name))
+            self.freq_shard_max_keys = max(
+                self.freq_shard_max_keys, other.freq_shard_max_keys
+            )
             self.bundle_dispatch_seconds += other.bundle_dispatch_seconds
             for phase, seconds in other.phase_seconds.items():
                 self.phase_seconds[phase] = (
@@ -428,15 +449,32 @@ class PackedScanProgram:
             )
 
         fused_update.__name__ = fused_update.__qualname__ = self.name
+        # a mesh program's carry is born on the mesh: left to jax it lands
+        # on the default device (device 0), outside a sub-mesh that does
+        # not hold it, and the donated update then reads it cross-chip.
+        # Leaves an analyzer splits over the row axis stay split; the rest
+        # are replicated.
+        self._carry_sharding = None
+        if mesh is not None:
+            from ..parallel import replicated, row_sharded
+
+            split = set()
+            offset = 0
+            for a, shapes in zip(analyzers, init_shapes):
+                split.update(offset + j for j in a.row_split_leaves())
+                offset += len(jax.tree_util.tree_leaves(shapes))
+            rep = replicated(mesh)
+            self._carry_sharding = (rep, rep, tuple(
+                row_sharded(mesh) if i in split else rep
+                for i in self._aux_idx
+            ))
         if mesh is None:
             self._update = jax.jit(fused_update, donate_argnums=0)
         else:
-            from ..parallel import replicated
-
             self._update = jax.jit(
                 fused_update,
-                in_shardings=(replicated(mesh), None),
-                out_shardings=replicated(mesh),
+                in_shardings=(self._carry_sharding, None),
+                out_shardings=self._carry_sharding,
                 donate_argnums=0,
             )
         #: the raw traced bodies, kept so the cross-session COALESCED path
@@ -454,10 +492,6 @@ class PackedScanProgram:
         # copy was measurable (~0.26s at 256MB on CPU). NEVER use for the
         # mid-pass checkpoint unpack, whose carry keeps folding.
         self._unpack_final_jit = jax.jit(unpack, donate_argnums=0)
-        # a mesh program's carry is born on the mesh: left to jax it lands
-        # on the default device (device 0), outside a sub-mesh that does
-        # not hold it, and the donated update then reads it cross-chip
-        self._carry_sharding = None if mesh is None else replicated(mesh)
         self._init_jit = jax.jit(
             lambda: pack(tuple(a.init_state() for a in analyzers)),
             out_shardings=self._carry_sharding,
@@ -1605,6 +1639,30 @@ _FEED_LATENCY_S: Optional[float] = None
 _FEED_BANDWIDTH_THRESHOLD_MBPS = 100.0
 
 
+def _drain_frequency_tables(analyzers, states, mesh, monitor) -> List[Any]:
+    """Pass end of the device frequency tables: each table is counted on
+    the device (split over ``mesh`` when its state is) and only its
+    summary comes to the host (``freq_drain``: pass end to the grouping
+    state in host memory). Every drain is dispatched before the first
+    fetch blocks."""
+    if not analyzers:
+        return []
+    with monitor.timed("freq_drain"):
+        drained = [a.drain(st, mesh) for a, st in zip(analyzers, states)]
+        out = []
+        for a, d in zip(analyzers, drained):
+            summary, stats = a.fetch_summary(d)
+            with _MONITOR_LOCK:
+                monitor.freq_device_summaries += 1
+                monitor.freq_keys_drained += stats["keys"]
+                monitor.freq_drain_fetch_bytes += stats["fetch_bytes"]
+                monitor.freq_shard_max_keys = max(
+                    monitor.freq_shard_max_keys, stats["busiest_shard_keys"]
+                )
+            out.append(summary)
+    return out
+
+
 def probe_feed_bandwidth() -> float:
     """Measured round-trip bandwidth (MB/s) of the default-device feed link,
     cached per process. A put+get round trip forces a REAL transfer — put
@@ -2354,11 +2412,28 @@ class ScanEngine:
         with _MONITOR_LOCK:
             monitor.jit_compiles = max(monitor.jit_compiles, compiled)
             monitor.program_compiles += max(0, compiled - compiled_before)
+        tables = [
+            i for i, a in enumerate(self.scan_analyzers)
+            if isinstance(a, DeviceFrequencyTableScan)
+        ]
+        summaries = _drain_frequency_tables(
+            [self.scan_analyzers[i] for i in tables],
+            [states[i] for i in tables], self.mesh, monitor,
+        )
+        rest = [i for i in range(len(states)) if i not in tables]
         with monitor.timed("state_fetch"):
-            host_side = _fetch_states_packed(
-                states,
-                analyzers=tuple(self.scan_analyzers) if slim_fetch else None,
+            fetched = _fetch_states_packed(
+                tuple(states[i] for i in rest),
+                analyzers=(
+                    tuple(self.scan_analyzers[i] for i in rest)
+                    if slim_fetch else None
+                ),
             )
+        host_side: List[Any] = [None] * len(states)
+        for i, st in zip(rest, fetched):
+            host_side[i] = st
+        for i, summary in zip(tables, summaries):
+            host_side[i] = summary
         if not self._cancelled.is_set():
             cost_ledger.flush(monitor)
         return host_side, host_states

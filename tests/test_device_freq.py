@@ -60,6 +60,7 @@ def _parity(data, cols, monkeypatch, expect_device_sets=1, batch_size=None):
         mon.device_freq_sets, expect_device_sets
     )
     assert mon.freq_overflow_fallbacks == 0
+    assert mon.freq_device_summaries == expect_device_sets
     monkeypatch.setenv("DEEQU_TPU_DEVICE_FREQ", "0")
     try:
         host = _values(_run(data, battery, **kw), battery)
@@ -436,28 +437,29 @@ class TestStateMergePaths:
                 )
             return st
 
-        whole = scan.drain(fold(keys))
-        halves = scan.merge(fold(keys[:8192]), fold(keys[8192:]))
-        merged = scan.drain(halves)
+        def drained(st):
+            return scan.fetch_summary(scan.drain(st))[0]
 
-        def pairs(hf):
-            # key ORDER is not part of the HashedFrequencies contract (the
-            # native drain emits in probe order) — the multiset is
-            order = np.argsort(hf.keys)
-            return hf.keys[order].tolist(), hf.counts[order].tolist()
+        whole = drained(fold(keys))
+        merged = drained(scan.merge(fold(keys[:8192]), fold(keys[8192:])))
+        from deequ_tpu.analyzers.grouping import _sum_c_ln_c
 
-        assert pairs(whole) == pairs(merged)
-        assert whole.num_rows == merged.num_rows
+        _, counts = np.unique(keys, return_counts=True)
+        assert whole.stream_summary() == (
+            len(counts), int((counts == 1).sum()), len(keys),
+            _sum_c_ln_c(counts),
+        )
+        assert whole.num_rows == merged.num_rows == len(keys)
         assert whole.stream_summary() == merged.stream_summary()
 
     def test_hashed_frequencies_refuses_value_keyed_merge(self):
         from deequ_tpu.analyzers.grouping import (
             FrequenciesAndNumRows,
-            HashedFrequencies,
+            FrequencySummary,
         )
 
-        hf = HashedFrequencies(
-            np.array([1], dtype=np.uint64), np.array([2], dtype=np.int64), 2, ["k"]
+        hf = FrequencySummary(
+            1, 0, 2, np.array([2]), np.array([1]), 2, ["k"]
         )
         with pytest.raises(TypeError, match="never mix"):
             hf.sum(FrequenciesAndNumRows.empty(["k"]))

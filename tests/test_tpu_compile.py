@@ -168,3 +168,52 @@ def test_sharded_update_over_four_chips(topo):
         states, _features(battery, batch, rows_sharded)
     ).compile()
     assert "all-reduce" in compiled.as_text()
+
+
+def test_split_frequency_table_over_four_chips(topo):
+    """The frequency table of a resident SF 4 pass split over a 2x2 mesh
+    (2^23 keys a chip): the update appends each chip's rows locally, and
+    the pass-end drain exchanges keys by range (all-to-all) and sums the
+    chips' summaries. The TPU lowers no s64 max all-reduce."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from deequ_tpu.analyzers.grouping import (
+        DeviceFrequencyTableScan,
+        _drain_program,
+    )
+    from deequ_tpu.parallel import ROW_AXIS, make_mesh
+    from deequ_tpu.runners.engine import PackedScanProgram
+
+    mesh = make_mesh(devices=topo.devices[:4])
+    scan = DeviceFrequencyTableScan(
+        ("a", "b"), ("num", "num"), 8, 1 << 23, resident=True, shards=4
+    )
+    prog = PackedScanProgram((scan,), mesh)
+    carry = jax.tree_util.tree_map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        jax.eval_shape(prog.init_carry), prog._carry_sharding,
+    )
+    rows = NamedSharding(mesh, PartitionSpec(ROW_AXIS))
+    features = tuple(
+        jax.ShapeDtypeStruct((BATCH,), dtype, sharding=rows)
+        for dtype in ("bool", "bool", "float64", "bool", "float64")
+    )
+    update = prog._update.lower(carry, (features,)).compile()
+    assert "all-gather" not in update.as_text()
+
+    split = NamedSharding(mesh, PartitionSpec(ROW_AXIS))
+    replicated = NamedSharding(mesh, PartitionSpec())
+    state = jax.eval_shape(scan.init_state)
+    state = state.replace(**{
+        name: jax.ShapeDtypeStruct(
+            getattr(state, name).shape, getattr(state, name).dtype,
+            sharding=split if name == "buf" else replicated,
+        )
+        for name in state.__dataclass_fields__
+    })
+    drain = _drain_program(mesh, 4, True, scan.hist_slots()).lower(
+        state
+    ).compile()
+    assert "all-to-all" in drain.as_text()
+    assert drain.memory_analysis().temp_size_in_bytes < 4 << 30
