@@ -16,6 +16,7 @@ Replaces: Spark `DataFrame` + Row null checks (deequ uses `isNotNull` /
 from __future__ import annotations
 
 import enum
+import threading
 from dataclasses import dataclass
 from typing import Any, Dict, Iterator, List, Mapping, Optional, Sequence, Union
 
@@ -175,11 +176,10 @@ class Column:
         need presence/length/native-kernel input use ``has_dictionary`` /
         ``num_categories`` / ``dictionary_source`` instead."""
         if self._dictionary is None and self._dictionary_arrow is not None:
-            vals = self.aux.get("values")
-            if vals is None or len(vals) != len(self._dictionary_arrow):
-                vals = _decode_dictionary(self._dictionary_arrow, self.kind)
-                self.aux["values"] = vals
-            self._dictionary = vals
+            self._dictionary = aux_once(
+                self.aux, "values",
+                lambda: _decode_dictionary(self._dictionary_arrow, self.kind),
+            )
         return self._dictionary
 
     @property
@@ -476,30 +476,47 @@ class Dataset:
             values = np.asarray(arr.to_pylist(), dtype=object)
         return Column(name, kind, values, mask)
 
+    def num_batches(self, batch_size: int) -> int:
+        """How many batches ``batches(batch_size)`` yields: an empty table
+        yields one empty batch."""
+        return max(1, -(-self._table.num_rows // batch_size))
+
+    def batch_at(
+        self,
+        index: int,
+        batch_size: int,
+        columns: Optional[Sequence[str]] = None,
+        pad_to_batch_size: bool = True,
+    ) -> Batch:
+        """Batch ``index`` of ``batches(batch_size, columns,
+        pad_to_batch_size)``, sliced on its own: threads that stage
+        different batches share no iterator."""
+        if not 0 <= index < self.num_batches(batch_size):
+            raise IndexError(f"batch {index} of {self.num_batches(batch_size)}")
+        names = list(columns) if columns is not None else self._schema.names
+        start = index * batch_size
+        m = min(batch_size, self._table.num_rows - start)
+        cols: Dict[str, Column] = {}
+        for name in names:
+            col = self._materialize_column(
+                name, self._table.column(name).slice(start, batch_size)
+            )
+            if pad_to_batch_size and m < batch_size:
+                col = _pad_column(col, batch_size)
+            cols[name] = col
+        size = batch_size if pad_to_batch_size else m
+        row_mask = np.zeros(size, dtype=bool)
+        row_mask[:m] = True
+        return Batch(cols, row_mask, m)
+
     def batches(
         self,
         batch_size: int,
         columns: Optional[Sequence[str]] = None,
         pad_to_batch_size: bool = True,
     ) -> Iterator[Batch]:
-        names = list(columns) if columns is not None else self._schema.names
-        table = self._table.select(names) if names != self._schema.names else self._table
-        n = table.num_rows
-        for start in range(0, max(n, 1), batch_size):
-            sl = table.slice(start, batch_size)
-            m = min(batch_size, n - start)  # not sl.num_rows: 0-col tables misreport
-            cols: Dict[str, Column] = {}
-            for name in names:
-                col = self._materialize_column(name, sl[name])
-                if pad_to_batch_size and m < batch_size:
-                    col = _pad_column(col, batch_size)
-                cols[name] = col
-            size = batch_size if pad_to_batch_size else m
-            row_mask = np.zeros(size, dtype=bool)
-            row_mask[:m] = True
-            yield Batch(cols, row_mask, m)
-            if n == 0:
-                break
+        for index in range(self.num_batches(batch_size)):
+            yield self.batch_at(index, batch_size, columns, pad_to_batch_size)
 
 
 #: set to "0" to disable ingest-time adaptive dictionary encoding
@@ -608,6 +625,34 @@ def _numeric_buffer_view(arr: "pa.Array", n: int) -> Optional[np.ndarray]:
     return view[arr.offset:]
 
 
+#: the ``Column.aux`` key of the lock that guards its tables' creation
+_AUX_LOCK = "lock"
+
+
+def _aux_lock(aux: dict) -> "threading.RLock":
+    lock = aux.get(_AUX_LOCK)
+    if lock is None:
+        lock = aux.setdefault(_AUX_LOCK, threading.RLock())
+    return lock
+
+
+def aux_once(aux: dict, key: Any, compute) -> Any:
+    """``aux[key]``, set to ``compute()`` the first time it is asked for.
+    Batches staged on several threads share one ``aux`` per dataset
+    column: the first caller computes the table under the column's lock
+    while the others wait for it, so each table is computed once and
+    published whole. ``compute`` may itself call ``aux_once`` on the same
+    ``aux`` (the lock is reentrant)."""
+    value = aux.get(key)
+    if value is None:
+        with _aux_lock(aux):
+            value = aux.get(key)
+            if value is None:
+                value = compute()
+                aux[key] = value
+    return value
+
+
 def _decode_dictionary(dictionary: "pa.Array", kind: ColumnKind) -> np.ndarray:
     """The single decode policy for dictionary payloads — shared by batch
     materialization and Dataset.dictionary_values so the code->value mapping
@@ -638,8 +683,12 @@ def _materialize_dictionary(
         aux = {}
     num_cats = len(arr.dictionary)
     if aux.get("num_categories") != num_cats:
-        aux.clear()  # dictionary changed: derived artifacts are stale
-        aux["num_categories"] = num_cats
+        with _aux_lock(aux):
+            if aux.get("num_categories") != num_cats:
+                # dictionary changed: derived artifacts are stale
+                lock = aux[_AUX_LOCK]
+                aux.clear()
+                aux.update({_AUX_LOCK: lock, "num_categories": num_cats})
     indices = arr.indices
     if indices.null_count == 0 and indices.type == pa.int32():
         # the common fast shape (int32 indices, no nulls): zero-copy view,

@@ -1,39 +1,46 @@
-"""Double-buffered host->device feed pipeline.
+"""Staged host->device feed pipeline.
 
 The engine's device pass is a chain of async XLA dispatches; what gates it
-is the HOST side of each batch — feature build plus the ``jax.device_put``
-host->device copy. This module is the reusable seam: a dedicated feed
-thread stages batch k+1 (and with depth 2, k+2) — building features and
-starting its device transfer — while batch k's fold executes, so the
-transfer time hides under device compute instead of serializing with it.
+is the HOST side of each batch — slicing, feature build and the
+``jax.device_put`` host->device copy. This module is the reusable seam: a
+pool of feed workers stages the batches ahead of the fold — each worker
+takes the next batch index, builds its features and starts its device
+transfer — while the dispatching thread folds the staged batches strictly
+in batch order, so the states fold exactly as a serial pass folds them.
+The staging work releases the GIL (numpy over whole batches, the native
+packers, pyarrow slicing), so several workers overlap on the host's cores.
 Batch shapes stay pow2-bucketed upstream (`service.streaming` /
 `runners.engine.effective_batch_size`), so staging ahead never provokes a
 recompile — every staged batch reuses the one compiled program shape.
 
-``DEEQU_TPU_PREFETCH_DEPTH`` sizes the pipeline (default 2 = classic
-double buffering: one batch in flight on device, one staged, one being
-built). ``0`` disables the feed thread entirely — batches produce inline
-on the consumer thread — which is the measured "serial" baseline the
-PERF.md overlap numbers compare against. Unparseable values warn once and
-keep the default (the watchdog env convention).
+``DEEQU_TPU_PREFETCH_DEPTH`` sizes the pipeline (default 2): at most
+``workers + depth`` batches are staged, or being staged, ahead of the one
+the consumer takes next, which bounds host and device memory. ``0``
+disables the feed threads entirely — batches produce inline on the
+consumer thread — which is the measured "serial" baseline. Unparseable
+values warn once and keep the default (the watchdog env convention).
+``feed_workers`` sizes the pool from what the process can observe: the
+CPUs it may use and the pass's batch count.
 
-Failure contract: an exception inside the feed thread (a poisoned batch,
-an injected ``feed_stall``, a device_put infrastructure error) propagates
-to the consumer on its next pull — same semantics as the inline path —
-and the pipeline shuts down; a feed thread that goes SILENT (a hung
-transfer that neither returns nor raises) trips the consumer's stall
-deadline (``DEEQU_TPU_FEED_STALL_S``, default 120s, <=0 disables) as a
-typed ``FeedStallError``, which is a ``DeviceFailureException`` — the
-pass fails over to the host tier exactly like a thrown device fault. The
-``prefetch`` fault site fires before each staged batch so chaos tests
+Failure contract: an exception while staging batch k (a poisoned batch,
+an injected ``feed_stall``, a device_put infrastructure error) reaches
+the consumer when it reaches batch k — after every earlier batch, same
+semantics as the inline path — and the pool shuts down; a pool that goes
+SILENT (a hung transfer that neither returns nor raises) trips the
+consumer's stall deadline (``DEEQU_TPU_FEED_STALL_S``, default 120s, <=0
+disables) as a typed ``FeedStallError``, which is a
+``DeviceFailureException`` — the pass fails over to the host tier exactly
+like a thrown device fault. The ``prefetch`` fault site fires once before
+each staged batch, in batch order, tagged with its index, so chaos tests
 can wedge or kill the feed on demand.
 """
 
 from __future__ import annotations
 
-import queue
+import os
 import threading
-from typing import Any, Callable, Iterator, Optional
+import time
+from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 #: env var sizing the staged-batch pipeline (0 = serial, no feed thread)
 PREFETCH_DEPTH_ENV = "DEEQU_TPU_PREFETCH_DEPTH"
@@ -65,7 +72,7 @@ def feed_stall_s() -> float:
 def staging_depth(n_batches: int):
     """The pipeline depth for a pass of ``n_batches``: ``None`` (the
     configured depth) for multi-batch passes, ``0`` (inline, no feed
-    thread) for a single-batch pass. Double-buffering a one-batch fold
+    thread) for a single-batch pass. Staging a one-batch fold ahead
     has nothing to overlap with, so the feed thread's spawn/teardown is
     pure fixed cost — measurable on the streaming plane, where every
     micro-batch fold is a one-batch pass (the ~50ms/fold knee diet). The
@@ -75,83 +82,118 @@ def staging_depth(n_batches: int):
     return 0 if n_batches <= 1 else None
 
 
-#: queue sentinel kinds
-_ITEM, _DONE, _ERROR = 0, 1, 2
+def feed_workers(n_batches: int) -> int:
+    """The feed pool's size for a pass of ``n_batches``: half the CPUs
+    this process may use, which leaves cores to the dispatching thread and
+    XLA's host threads, and no more workers than batches. One usable CPU,
+    or one batch, gives the one feed thread."""
+    try:
+        cpus = len(os.sched_getaffinity(0))
+    except AttributeError:  # pragma: no cover - platforms without affinity
+        cpus = os.cpu_count() or 1
+    return max(1, min(int(n_batches), cpus // 2))
 
 
 class PrefetchingBatchIterator:
-    """Iterate ``produce()`` results through a bounded staging pipeline.
+    """Iterate ``produce(index)`` for ``index = start, ..., count - 1``
+    through a bounded staging pool, in index order.
 
-    ``produce`` is called repeatedly on the feed thread; it returns the
-    next staged item or ``None`` at end of input (the engine's existing
-    producer contract). Up to ``depth`` finished items wait in the stage
-    queue while the consumer folds; ``depth=0`` degenerates to calling
-    ``produce`` inline (no thread, bit-identical ordering).
+    ``produce(index)`` runs on a feed worker and returns the staged item
+    of batch ``index``. ``workers`` threads stage concurrently; a worker
+    takes index ``i`` only while ``i < next + workers + depth``, where
+    ``next`` is the index the consumer takes next. ``depth=0`` degenerates
+    to calling ``produce`` inline (no thread, bit-identical ordering).
 
-    The iterator is a context manager; exiting (or ``close()``) tears the
-    feed thread down even when the consumer stopped early."""
+    ``inflight_peak`` is the most items that were being staged at once.
+    The iterator is a context manager; exiting (or ``close()``) tears
+    every worker down even when the consumer stopped early."""
 
     def __init__(
         self,
-        produce: Callable[[], Optional[Any]],
+        produce: Callable[[int], Any],
+        count: int,
         *,
+        start: int = 0,
         depth: Optional[int] = None,
+        workers: int = 1,
         stall_timeout_s: Optional[float] = None,
         name: str = "deequ-ingest-prefetch",
     ):
         self._produce = produce
         self.depth = prefetch_depth() if depth is None else max(0, int(depth))
-        #: how long the consumer tolerates a SILENT feed thread before
-        #: raising typed FeedStallError (<= 0 disables the deadline)
+        #: feed threads staging items (0: inline on the consumer thread)
+        self.workers = max(1, int(workers)) if self.depth > 0 else 0
+        #: how long the consumer tolerates a SILENT pool before raising
+        #: typed FeedStallError (<= 0 disables the deadline)
         self.stall_timeout_s = (
             feed_stall_s() if stall_timeout_s is None else float(stall_timeout_s)
         )
-        self._closed = threading.Event()
-        self._queue: Optional[queue.Queue] = None
-        self._thread: Optional[threading.Thread] = None
-        self._index = 0
-        if self.depth > 0:
-            self._queue = queue.Queue(maxsize=self.depth)
-            self._thread = threading.Thread(
-                target=self._feed_loop, name=name, daemon=True
+        self.inflight_peak = 0
+        self._inflight = 0
+        self._next = start  # the index the consumer takes next
+        self._claim = start  # the index a worker stages next
+        #: first index no worker stages: ``count``, or a failed item's
+        self._end = count
+        #: finished items by index: (value, None) or (None, exception)
+        self._slots: Dict[int, Tuple[Any, Optional[BaseException]]] = {}
+        self._progress = time.monotonic()  # when a worker last finished
+        self._closed = False
+        self._cond = threading.Condition()
+        #: held from a claim through its fault probe: probes fire in index
+        #: order, so a plan's ``at=k`` hits batch k - 1 whatever the timing
+        self._claim_lock = threading.Lock()
+        self._threads: List[threading.Thread] = [
+            threading.Thread(
+                target=self._work, name=f"{name}-{k}", daemon=True
             )
-            self._thread.start()
+            for k in range(self.workers)
+        ]
+        for thread in self._threads:
+            thread.start()
 
-    # -- feed thread ---------------------------------------------------------
+    # -- feed workers --------------------------------------------------------
 
-    def _feed_loop(self) -> None:
+    def _work(self) -> None:
         from ..reliability.faults import fault_point
 
-        index = 0
-        while not self._closed.is_set():
+        while True:
+            with self._claim_lock:
+                with self._cond:
+                    while not self._closed and self._claim < self._end and (
+                        self._claim >= self._next + self.workers + self.depth
+                    ):
+                        self._cond.wait()
+                    if self._closed or self._claim >= self._end:
+                        return
+                    index = self._claim
+                    self._claim += 1
+                    self._inflight += 1
+                    self.inflight_peak = max(self.inflight_peak, self._inflight)
+                try:
+                    # chaos site: an injected feed_stall wedges/kills the
+                    # feed exactly where a real transfer thread would
+                    fault_point("prefetch", tag=str(index))
+                except BaseException as exc:  # noqa: BLE001
+                    self._finish(index, None, exc)
+                    return
             try:
-                # chaos site: an injected feed_stall wedges/kills the feed
-                # exactly where a real transfer thread would
-                fault_point("prefetch", tag=str(index))
-                item = self._produce()
+                item = self._produce(index)
             except BaseException as exc:  # noqa: BLE001 - propagate to
                 # the consumer: KeyboardInterrupt-class injections must
                 # ride out exactly like on the inline path
-                self._put((_ERROR, exc))
+                self._finish(index, None, exc)
                 return
-            if item is None:
-                self._put((_DONE, None))
-                return
-            if not self._put((_ITEM, item)):
-                return  # consumer closed while we were staging
-            index += 1
+            self._finish(index, item, None)
 
-    def _put(self, entry) -> bool:
-        """Bounded put that aborts when the consumer closed the pipeline
-        (a consumer that stopped early must not leave this thread parked
-        on a full queue forever)."""
-        while not self._closed.is_set():
-            try:
-                self._queue.put(entry, timeout=0.1)
-                return True
-            except queue.Full:
-                continue
-        return False
+    def _finish(self, index: int, item: Any, error: Optional[BaseException]) -> None:
+        with self._cond:
+            self._inflight -= 1
+            self._progress = time.monotonic()
+            if not self._closed:
+                self._slots[index] = (item, error)
+                if error is not None:
+                    self._end = min(self._end, index)
+            self._cond.notify_all()
 
     # -- consumer side -------------------------------------------------------
 
@@ -160,24 +202,29 @@ class PrefetchingBatchIterator:
 
     def __next__(self) -> Any:
         if self.depth == 0:
-            from ..reliability.faults import fault_point
-
-            fault_point("prefetch", tag=str(self._index))
-            self._index += 1
-            item = self._produce()
-            if item is None:
-                raise StopIteration
-            return item
-        if self._closed.is_set():
-            raise StopIteration
+            return self._next_inline()
         deadline = self.stall_timeout_s
-        try:
-            if deadline and deadline > 0:
-                kind, value = self._queue.get(timeout=deadline)
-            else:
-                kind, value = self._queue.get()
-        except queue.Empty:
-            # the feed thread went SILENT past the stall deadline (a hung
+        with self._cond:
+            if self._closed or (
+                self._next >= self._end and self._next not in self._slots
+            ):
+                self._closed = True
+                raise StopIteration
+            waited_from = time.monotonic()
+            while self._next not in self._slots:
+                if deadline and deadline > 0:
+                    silent = time.monotonic() - max(waited_from, self._progress)
+                    if silent >= deadline:
+                        break
+                    self._cond.wait(deadline - silent)
+                else:
+                    self._cond.wait()
+            entry = self._slots.pop(self._next, None)
+            if entry is not None:
+                self._next += 1
+                self._cond.notify_all()  # a staging slot opened
+        if entry is None:
+            # the pool went SILENT past the stall deadline (a hung
             # device_put, a wedged source): declare it typed — a
             # DeviceFailureException, so the pass fails over to the host
             # tier, whose chunk iteration shares none of this machinery
@@ -186,29 +233,40 @@ class PrefetchingBatchIterator:
             self.close()
             raise FeedStallError(
                 "prefetch",
-                f"feed thread produced nothing for {deadline:.0f}s",
-            ) from None
-        if kind == _ITEM:
-            return value
-        self._closed.set()
-        if kind == _ERROR:
-            raise value
-        raise StopIteration
+                f"feed workers produced nothing for {deadline:.0f}s",
+            )
+        item, error = entry
+        if error is not None:
+            self.close()
+            raise error
+        return item
+
+    def _next_inline(self) -> Any:
+        from ..reliability.faults import fault_point
+
+        with self._cond:  # uncontended: no worker shares this state
+            if self._next >= self._end:
+                raise StopIteration
+            index = self._next
+            self._next += 1
+            self.inflight_peak = 1
+        fault_point("prefetch", tag=str(index))
+        return self._produce(index)
 
     def close(self) -> None:
-        """Tear the pipeline down (idempotent): wakes a feed thread parked
-        on a full queue and joins it. Staged-but-unconsumed items are
+        """Tear the pool down (idempotent): wakes every worker waiting for
+        a staging slot and joins them all. Staged-but-unconsumed items are
         dropped — the consumer abandoning a pass does exactly that."""
-        self._closed.set()
-        if self._thread is not None:
-            # drain so a blocked put's retry loop sees closed immediately
-            try:
-                while True:
-                    self._queue.get_nowait()
-            except queue.Empty:
-                pass
-            self._thread.join(timeout=5)
-            self._thread = None
+        with self._cond:
+            self._closed = True
+            self._slots.clear()
+            self._cond.notify_all()
+        # one deadline for the whole pool: a worker wedged inside produce
+        # cannot be joined, and must not hold the consumer for 5s apiece
+        join_by = time.monotonic() + 5.0
+        for thread in self._threads:
+            thread.join(timeout=max(0.0, join_by - time.monotonic()))
+        self._threads = []
 
     def __enter__(self) -> "PrefetchingBatchIterator":
         return self

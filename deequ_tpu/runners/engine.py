@@ -129,6 +129,11 @@ class RunMonitor:
     #: the most keys one device counted in a drain (after the exchange by
     #: key range on a mesh): the balance of the split table
     freq_shard_max_keys: int = 0
+    #: the largest feed pool a device pass of the run staged its batches
+    #: on (0: every pass staged inline on the dispatching thread)
+    feed_workers: int = 0
+    #: the most batches being staged at once in a pass of the run
+    feed_inflight_peak: int = 0
     #: mesh shards (devices/processes) declared lost mid-pass — dead
     #: collectives, injected mesh_loss faults, heartbeat-declared stalls
     shard_losses: int = 0
@@ -193,6 +198,8 @@ class RunMonitor:
         self.freq_keys_drained = 0
         self.freq_drain_fetch_bytes = 0
         self.freq_shard_max_keys = 0
+        self.feed_workers = 0
+        self.feed_inflight_peak = 0
         self.shard_losses = 0
         self.mesh_reshards = 0
         self.salvaged_states = 0
@@ -226,9 +233,10 @@ class RunMonitor:
                 "partitions_dropped", "partitions_rolled_up",
             ):
                 setattr(self, name, getattr(self, name) + getattr(other, name))
-            self.freq_shard_max_keys = max(
-                self.freq_shard_max_keys, other.freq_shard_max_keys
-            )
+            for name in (
+                "freq_shard_max_keys", "feed_workers", "feed_inflight_peak",
+            ):
+                setattr(self, name, max(getattr(self, name), getattr(other, name)))
             self.bundle_dispatch_seconds += other.bundle_dispatch_seconds
             for phase, seconds in other.phase_seconds.items():
                 self.phase_seconds[phase] = (
@@ -2020,10 +2028,14 @@ class ScanEngine:
         monitor: Optional[RunMonitor] = None,
         sharding: Optional[Any] = None,
         placement: Optional[str] = None,
+        feed_workers: Optional[int] = None,
     ):
         from ..utils import env_str
 
         self.scan_analyzers = list(scan_analyzers)
+        #: the feed pool's size for every device pass; None sizes it from
+        #: the CPUs and each pass's batch count (``ingest.feed_workers``)
+        self.feed_workers = feed_workers
         self.monitor = monitor or RunMonitor()
         #: set when the watchdog abandons this engine's pass: the zombie
         #: thread checks it before flushing its cost ledger, so an
@@ -2114,7 +2126,7 @@ class ScanEngine:
         )
 
         bs = effective_batch_size(data, batch_size)
-        n_batches = max(1, -(-int(data.num_rows) // bs))
+        n_batches = data.num_batches(bs)
         n_rows = max(1, int(data.num_rows))
         tier = self._resolve_placement_inner()
         cold = 0
@@ -2266,11 +2278,11 @@ class ScanEngine:
 
         compiled_before = compiled_count()
 
-        # pipelined pass: a single prefetch thread pulls batch i+1 and builds
-        # its features while the (async-dispatched) device program chews on
-        # batch i — the analog of Spark overlapping scan IO with aggregation
-        batches = data.batches(bs, columns=columns)
-
+        # pipelined pass: feed workers slice batches ahead and build their
+        # features while the (async-dispatched) device program chews on the
+        # batches before them — the analog of Spark overlapping scan IO
+        # with aggregation
+        n_total_batches = data.num_batches(bs)
         cache = device_feature_cache() if self._update is not None else None
         if cache is not None:
             cache_base = (
@@ -2279,26 +2291,22 @@ class ScanEngine:
                 None if columns is None else tuple(columns),
                 tuple(sorted(self.builder.specs)),
             )
-        import itertools
-
-        idx_counter = itertools.count()
-        # the prefetch worker builds features on its own thread: carry the
-        # trace context over so feature_build/device_feed phase spans stay
-        # children of this pass instead of orphaning
+        # feed workers build features on their own threads: carry the trace
+        # context over so feature_build/device_feed phase spans stay
+        # children of this pass instead of orphaning, and the cache-bypass
+        # flag so a warm run's features stay out of the cache budget
         trace_ctx = _trace.capture()
+        bypass = getattr(_CACHE_BYPASS, "active", False)
 
-        def produce():
+        def produce(index):
+            _CACHE_BYPASS.active = bypass
             with _trace.attach(trace_ctx):
-                return produce_inner()
+                return produce_inner(index)
 
-        def produce_inner():
-            index = next(idx_counter)
-            try:
-                # Arrow slicing, column materialization and padding
-                with monitor.timed("batch_slice"):
-                    batch = next(batches)
-            except StopIteration:
-                return None
+        def produce_inner(index):
+            # Arrow slicing, column materialization and padding
+            with monitor.timed("batch_slice"):
+                batch = data.batch_at(index, bs, columns=columns)
             if self._update is None:
                 return batch, None
             if cache is not None:
@@ -2338,9 +2346,6 @@ class ScanEngine:
             folded = resume.batch_index
             if self._update is not None:
                 carry = self._update.pack_states(tuple(resume.scan_states))
-            for _ in range(folded):
-                next(idx_counter)
-                next(batches)
 
         def save_checkpoint():
             with monitor.timed("checkpoint"):
@@ -2355,24 +2360,32 @@ class ScanEngine:
                 )
                 monitor.bump("checkpoint_saves")
 
-        # double-buffered feed pipeline (deequ_tpu.ingest.prefetch): the
-        # feed thread stages batch k+1's feature build + host->device copy
-        # (and with the default depth 2, k+2's) while batch k's fold
-        # executes — transfer time hides under device compute instead of
-        # serializing with it. DEEQU_TPU_PREFETCH_DEPTH=0 restores the
-        # serial path (the measured baseline for the overlap numbers).
-        # Single-batch passes (every streaming micro-batch fold) stage
-        # inline: there is nothing to overlap, and the feed-thread spawn
-        # was pure fixed cost on the micro-fold path.
-        from ..ingest.prefetch import PrefetchingBatchIterator, staging_depth
+        # staged feed pipeline (deequ_tpu.ingest.prefetch): a pool of feed
+        # workers stages the batches ahead — feature build + host->device
+        # copy — while this thread folds them strictly in batch order, so
+        # the states fold exactly as a serial pass folds them.
+        # DEEQU_TPU_PREFETCH_DEPTH=0 restores the serial path (the measured
+        # baseline for the overlap numbers). Single-batch passes (every
+        # streaming micro-batch fold) stage inline: there is nothing to
+        # overlap, and the feed-thread spawn was pure fixed cost on the
+        # micro-fold path.
+        from ..ingest.prefetch import (
+            PrefetchingBatchIterator,
+            feed_workers,
+            staging_depth,
+        )
 
-        n_total_batches = max(1, -(-int(data.num_rows) // bs))
         with PrefetchingBatchIterator(
-            produce, depth=staging_depth(n_total_batches)
+            produce, n_total_batches, start=folded,
+            depth=staging_depth(n_total_batches),
+            workers=(
+                self.feed_workers if self.feed_workers is not None
+                else feed_workers(n_total_batches - folded)
+            ),
         ) as staged:
             while True:
                 if staged.depth > 0:
-                    # this thread waits on the feed thread's staging
+                    # this thread waits on the feed workers' staging
                     with monitor.timed("feed_wait"):
                         item = next(staged, None)
                 else:
@@ -2396,6 +2409,15 @@ class ScanEngine:
                 folded += 1
                 if ckpt is not None and folded % ckpt.every == 0:
                     save_checkpoint()
+        with _MONITOR_LOCK:
+            monitor.feed_workers = max(monitor.feed_workers, staged.workers)
+            monitor.feed_inflight_peak = max(
+                monitor.feed_inflight_peak, staged.inflight_peak
+            )
+        pass_span = _trace.current_span()
+        if pass_span is not None:
+            pass_span.set_attr("feed_workers", staged.workers)
+            pass_span.set_attr("feed_inflight_peak", staged.inflight_peak)
         if ckpt is not None:
             ckpt.complete(ckpt_epoch)
         if carry is not None:

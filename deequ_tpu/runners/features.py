@@ -18,7 +18,7 @@ from typing import Any, Dict, Iterable, List, Sequence
 import numpy as np
 
 from ..analyzers.base import FeatureSpec
-from ..data import Batch, ColumnKind
+from ..data import Batch, ColumnKind, aux_once
 from ..expr import evaluate_predicate
 from ..ops.hashing import hash_column
 from ..ops.hll import hll_features, hll_pack_features, hll_pack_pairs
@@ -170,16 +170,17 @@ def column_regex_matches(col, pattern: str) -> np.ndarray:
 
 
 def _entry_table(col, key, per_entry, dtype) -> np.ndarray:
-    """A dictionary column's per-entry table, cached in ``col.aux[key]``
-    once per dataset: ``per_entry()`` for each DISTINCT value, then one
-    sentinel slot at index ``num_categories`` holding 0 — the code that
-    null and padding rows carry, so a gather needs no bounds pass."""
-    table = col.aux.get(key)
-    if table is None:
+    """A dictionary column's per-entry table, computed once per dataset
+    into ``col.aux[key]`` (``aux_once``): ``per_entry()`` for each
+    DISTINCT value, then one sentinel slot at index ``num_categories``
+    holding 0 — the code that null and padding rows carry, so a gather
+    needs no bounds pass."""
+    def build():
         table = np.zeros(col.num_categories + 1, dtype=dtype)
         table[:-1] = per_entry()
-        col.aux[key] = table  # published whole: concurrent feature builds race benignly
-    return table
+        return table
+
+    return aux_once(col.aux, key, build)
 
 
 def _gather_by_code(col, table: np.ndarray) -> np.ndarray:
@@ -227,11 +228,9 @@ def dict_entry_hll_pairs(col) -> np.ndarray:
     DISTINCT dictionary value, cached per dataset — the one table both the
     host tier's partials and the device feature (``dict_hll_packed``)
     read."""
-    pairs = col.aux.get("hll_pairs")
-    if pairs is None:
-        pairs = hll_features(dict_entry_hashes(col))
-        col.aux["hll_pairs"] = pairs
-    return pairs
+    return aux_once(
+        col.aux, "hll_pairs", lambda: hll_features(dict_entry_hashes(col))
+    )
 
 
 def dict_hll_packed(col) -> np.ndarray:
@@ -248,12 +247,11 @@ def dict_entry_hashes(col) -> np.ndarray:
     """xxhash64 of each DISTINCT dictionary value, cached per dataset —
     the one hash pass every dictionary consumer (per-row hashes, HLL
     register pairs) derives from."""
-    hd = col.aux.get("hashes")
-    if hd is None:
+    def build():
         ones = np.ones(col.num_categories, dtype=bool)
-        hd = hash_column(col.dictionary_source, ones, col.kind)
-        col.aux["hashes"] = hd
-    return hd
+        return hash_column(col.dictionary_source, ones, col.kind)
+
+    return aux_once(col.aux, "hashes", build)
 
 
 def dict_hashes(col) -> np.ndarray:
@@ -430,13 +428,15 @@ def _predicate_column(col):
         # evaluate on the DISTINCT entries and gather by code; the
         # entry table (with its None sentinel) caches per dataset
         num_cats = col.num_categories
-        entries = col.aux.get("pred_entries")
-        if entries is None or len(entries) != num_cats + 1:
+
+        def build():
             entries = np.empty(num_cats + 1, dtype=object)
             if num_cats:
                 entries[:num_cats] = col.dictionary
             entries[num_cats] = None
-            col.aux["pred_entries"] = entries
+            return entries
+
+        entries = aux_once(col.aux, "pred_entries", build)
         codes = np.where(
             col.mask & (col.codes >= 0) & (col.codes < num_cats),
             col.codes,

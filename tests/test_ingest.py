@@ -469,37 +469,28 @@ class TestHttpEndpoint:
 
 class TestPrefetch:
     def test_preserves_order_and_stops(self):
-        items = iter(range(10))
-
-        def produce():
-            return next(items, None)
-
-        with PrefetchingBatchIterator(produce, depth=2) as it:
+        with PrefetchingBatchIterator(lambda index: index, 10, depth=2) as it:
             assert list(it) == list(range(10))
 
     def test_serial_depth_zero_inline(self):
         calls = []
-        items = iter(range(4))
 
-        def produce():
+        def produce(index):
             calls.append(threading.current_thread().name)
-            return next(items, None)
+            return index
 
-        with PrefetchingBatchIterator(produce, depth=0) as it:
+        with PrefetchingBatchIterator(produce, 4, depth=0) as it:
             got = list(it)
         assert got == list(range(4))
         assert set(calls) == {threading.current_thread().name}
 
     def test_propagates_producer_exception(self):
-        state = {"n": 0}
-
-        def produce():
-            state["n"] += 1
-            if state["n"] == 3:
+        def produce(index):
+            if index == 2:
                 raise RuntimeError("boom")
-            return state["n"]
+            return index + 1
 
-        with PrefetchingBatchIterator(produce, depth=2) as it:
+        with PrefetchingBatchIterator(produce, 10, depth=2) as it:
             assert next(it) == 1
             assert next(it) == 2
             with pytest.raises(RuntimeError, match="boom"):
@@ -507,31 +498,169 @@ class TestPrefetch:
                     pass
 
     def test_close_unblocks_parked_producer(self):
-        def produce():
+        def produce(index):
             return "item"  # endless
 
-        it = PrefetchingBatchIterator(produce, depth=1)
+        it = PrefetchingBatchIterator(produce, 1 << 30, depth=1)
         assert next(it) == "item"
         it.close()  # must not hang on the full queue
-        assert it._thread is None
+        assert it._threads == []
 
     def test_silent_feed_trips_stall_deadline_typed(self):
         from deequ_tpu.exceptions import FeedStallError
 
         wedge = threading.Event()
 
-        def produce():
+        def produce(index):
             wedge.wait(30)  # a hung transfer: never returns, never raises
             return None
 
         with PrefetchingBatchIterator(
-            produce, depth=1, stall_timeout_s=0.3
+            produce, 1, depth=1, stall_timeout_s=0.3
         ) as it:
             t0 = time.perf_counter()
             with pytest.raises(FeedStallError):
                 next(it)
             assert 0.2 <= time.perf_counter() - t0 < 10.0
         wedge.set()
+
+    @staticmethod
+    def _out_of_order(taken=None, bound=None, breaches=None):
+        """produce(index) whose batches finish out of order (later
+        batches of each triple sleep less); with ``taken`` it records any
+        claim past ``bound`` items ahead of the consumer."""
+        def produce(index):
+            if breaches is not None and index >= taken[0] + bound:
+                breaches.append(index)
+            time.sleep(0.01 * (2 - index % 3))
+            return index
+
+        return produce
+
+    @pytest.mark.parametrize("workers,depth", [(1, 2), (3, 1), (4, 2)])
+    def test_pool_yields_in_batch_order_within_bound(self, workers, depth):
+        taken, breaches = [0], []
+        # the consumer's count lags the iterator's by the item in hand
+        bound = workers + depth + 1
+        produce = self._out_of_order(taken, bound, breaches)
+        got = []
+        with PrefetchingBatchIterator(
+            produce, 30, depth=depth, workers=workers
+        ) as it:
+            for item in it:
+                got.append(item)
+                taken[0] += 1
+                time.sleep(0.002)
+        assert got == list(range(30))
+        assert breaches == []
+        assert it.workers == workers
+        assert 1 <= it.inflight_peak <= workers
+        if workers > 1:
+            assert it.inflight_peak > 1
+
+    @pytest.mark.parametrize("workers", [1, 4])
+    @pytest.mark.parametrize("fail_at", [0, 5])
+    def test_pool_error_surfaces_at_its_batch(self, workers, fail_at):
+        def produce(index):
+            if index == fail_at:
+                time.sleep(0.05)  # later batches finish first
+                raise RuntimeError(f"boom {index}")
+            return index
+
+        got = []
+        it = PrefetchingBatchIterator(produce, 12, depth=2, workers=workers)
+        with pytest.raises(RuntimeError, match=f"boom {fail_at}"):
+            for item in it:
+                got.append(item)
+        assert got == list(range(fail_at))
+        assert it._threads == []  # the pool shut down
+        with pytest.raises(StopIteration):
+            next(it)
+
+    @pytest.mark.parametrize("workers", [1, 4])
+    def test_pool_fault_site_fires_once_per_index(self, workers):
+        produce = self._out_of_order()
+        with inject(FaultSpec("prefetch", "stall", count=None)) as inj:
+            with PrefetchingBatchIterator(
+                produce, 8, depth=2, workers=workers
+            ) as it:
+                assert list(it) == list(range(8))
+        assert inj.fired == [f"prefetch:{i}:stall" for i in range(8)]
+        with inject(FaultSpec("prefetch", "feed_stall", at=3)) as inj:
+            from deequ_tpu.exceptions import FeedStallError
+
+            got = []
+            with PrefetchingBatchIterator(
+                produce, 8, depth=2, workers=workers
+            ) as it:
+                with pytest.raises(FeedStallError):
+                    for item in it:
+                        got.append(item)
+        assert inj.fired == ["prefetch:2:feed_stall"]
+        assert got == [0, 1]
+
+    @pytest.mark.parametrize("workers", [1, 4])
+    @pytest.mark.parametrize("wedged", ["all", "first"])
+    def test_pool_silent_producer_trips_stall(self, workers, wedged):
+        from deequ_tpu.exceptions import FeedStallError
+
+        wedge = threading.Event()
+
+        def produce(index):
+            if wedged == "all" or index == 0:
+                wedge.wait(30)  # hung: never returns, never raises
+            return index
+
+        try:
+            with PrefetchingBatchIterator(
+                produce, 20, depth=2, workers=workers,
+                stall_timeout_s=0.3,
+            ) as it:
+                t0 = time.perf_counter()
+                with pytest.raises(FeedStallError):
+                    next(it)
+                assert 0.2 <= time.perf_counter() - t0 < 10.0
+        finally:
+            wedge.set()
+
+    @pytest.mark.parametrize("workers", [1, 4])
+    def test_pool_close_mid_pass_joins_every_worker(self, workers):
+        def produce(index):
+            time.sleep(0.01)
+            return index  # endless
+
+        name = f"pool-close-{workers}"
+        it = PrefetchingBatchIterator(
+            produce, 1 << 30, depth=2, workers=workers, name=name
+        )
+        assert [next(it), next(it)] == [0, 1]
+        it.close()
+        assert it._threads == []
+        assert not [
+            t for t in threading.enumerate() if t.name.startswith(name)
+        ]
+
+    def test_pool_depth_zero_stays_inline(self):
+        calls = []
+
+        def produce(index):
+            calls.append(threading.current_thread().name)
+            return index
+
+        with PrefetchingBatchIterator(
+            produce, 5, start=2, depth=0, workers=4
+        ) as it:
+            assert list(it) == [2, 3, 4]
+            assert it.workers == 0
+        assert set(calls) == {threading.current_thread().name}
+
+    def test_feed_workers_follow_cpus_and_batches(self, monkeypatch):
+        from deequ_tpu.ingest import prefetch as pf
+
+        monkeypatch.setattr(pf.os, "sched_getaffinity", lambda pid: set(range(13)))
+        assert [pf.feed_workers(n) for n in (1, 4, 6, 23)] == [1, 4, 6, 6]
+        monkeypatch.setattr(pf.os, "sched_getaffinity", lambda pid: {0})
+        assert pf.feed_workers(23) == 1
 
     def test_env_depth_warn_and_fallback(self, monkeypatch):
         from deequ_tpu.ingest import prefetch as pf
