@@ -6,13 +6,13 @@
 One chip (the default):
 
 - **verify**: a ``VerificationSuite`` run over the BASELINE config-2 shape
-  (``bench.build_scan_data``: four nullable normal columns and a 100k-key
+  (``build_scan_data``: four nullable normal columns and a 100k-key
   categorical, plus a nullable three-value flag for the histogram) under the
   default ``auto`` placement. Its Uniqueness/Distinctness/CountDistinct set
   takes the compacting device frequency table (the key buffer is set below
   the row count, so the table compacts in the pass).
 - **profile**: a ``ColumnProfilerRunner`` full profile of the TPC-H
-  lineitem-shaped table (``bench.build_lineitem_data``).
+  lineitem-shaped table (``build_lineitem_data``).
 
 Four chips (``--chips 4``) runs only the multi-chip path and what it is
 compared with: the same verification over ``make_mesh(4)`` and through a
@@ -83,10 +83,63 @@ def log(msg: str) -> None:
 # ---------------------------------------------------------------------------
 
 
-def scan_table(rows: int, seed: int):
+def build_scan_data(rows: int, seed: int = 42):
     import pyarrow as pa
 
-    from bench import build_scan_data
+    rng = np.random.default_rng(seed)
+    cols = {}
+    for i in range(4):
+        vals = rng.normal(100 * i, 10, rows)
+        nulls = rng.random(rows) < 0.05
+        cols[f"x{i}"] = pa.array(vals, mask=nulls)
+    cols["cat"] = pa.array(rng.integers(0, 100_000, rows))
+    return pa.table(cols)
+
+
+def build_lineitem_data(rows: int, seed: int = 19):
+    """TPC-H lineitem-shaped synthetic (BASELINE config 3): the 16 lineitem
+    columns with realistic types/cardinalities — 4 int keys, 4 numeric
+    measures, 2 flags, 3 dates (strings), ship instruction/mode categories,
+    and a high-cardinality comment column (dictionary-encoded pool)."""
+    import pyarrow as pa
+
+    rng = np.random.default_rng(seed)
+    cols = {}
+    cols["l_orderkey"] = pa.array(rng.integers(1, max(rows // 4, 2), rows))
+    cols["l_partkey"] = pa.array(rng.integers(1, 200_001, rows))
+    cols["l_suppkey"] = pa.array(rng.integers(1, 10_001, rows))
+    cols["l_linenumber"] = pa.array(rng.integers(1, 8, rows))
+    cols["l_quantity"] = pa.array(rng.integers(1, 51, rows).astype(np.float64))
+    cols["l_extendedprice"] = pa.array(np.round(rng.uniform(900, 105_000, rows), 2))
+    cols["l_discount"] = pa.array(np.round(rng.uniform(0, 0.10, rows), 2))
+    cols["l_tax"] = pa.array(np.round(rng.uniform(0, 0.08, rows), 2))
+    flags = np.array(["A", "N", "R"])
+    cols["l_returnflag"] = pa.array(flags[rng.integers(0, 3, rows)])
+    status = np.array(["F", "O"])
+    cols["l_linestatus"] = pa.array(status[rng.integers(0, 2, rows)])
+    day0 = np.datetime64("1992-01-01")
+    for name in ("l_shipdate", "l_commitdate", "l_receiptdate"):
+        days = rng.integers(0, 2526, rows)  # 1992-01-01 .. 1998-12-01
+        dates = (day0 + days.astype("timedelta64[D]")).astype("datetime64[D]")
+        dic = pa.array(np.unique(dates).astype(str))
+        codes = pa.array(
+            np.searchsorted(np.unique(days), days).astype(np.int32)
+        )
+        cols[name] = pa.DictionaryArray.from_arrays(codes, dic)
+    instr = np.array(["DELIVER IN PERSON", "COLLECT COD", "NONE", "TAKE BACK RETURN"])
+    cols["l_shipinstruct"] = pa.array(instr[rng.integers(0, 4, rows)])
+    modes = np.array(["AIR", "FOB", "MAIL", "RAIL", "REG AIR", "SHIP", "TRUCK"])
+    cols["l_shipmode"] = pa.array(modes[rng.integers(0, 7, rows)])
+    pool = np.array(
+        [f"comment text fragment number {i} about the order" for i in range(1_000_000)]
+    )
+    codes = pa.array(rng.integers(0, len(pool), rows).astype(np.int32))
+    cols["l_comment"] = pa.DictionaryArray.from_arrays(codes, pa.array(pool))
+    return pa.table(cols)
+
+
+def scan_table(rows: int, seed: int):
+    import pyarrow as pa
 
     table = build_scan_data(rows, seed=seed)
     rng = np.random.default_rng(seed + 1)
@@ -399,7 +452,6 @@ def phase_verify(checks: Checks, counter: CompileCounter, rows: int, seed: int):
 
 
 def phase_profile(checks: Checks, counter: CompileCounter, rows: int, seed: int):
-    from bench import build_lineitem_data
     from deequ_tpu.data import Dataset
     from deequ_tpu.profiles import ColumnProfilerRunner
     from deequ_tpu.runners import RunMonitor

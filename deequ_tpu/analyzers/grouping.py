@@ -578,10 +578,9 @@ DEFAULT_FREQ_BUFFER_ENTRIES = 1 << 25  # 256MB of u64 keys
 
 #: env var gating the pre-routing cardinality probe ("0" disables it, so
 #: every eligible grouping set takes the device table no matter how small
-#: it looks — tools/grouping_sweep uses this to measure the raw table
-#: curve). With the probe on, sets that confidently look low-cardinality
-#: stay on the host group-by, whose value_counts fast path wins below the
-#: sweep knee.
+#: it looks). With the probe on, sets that confidently look
+#: low-cardinality stay on the host group-by, whose value_counts fast path
+#: wins below the crossover.
 FREQ_HOST_ROUTE_ENV = "DEEQU_TPU_FREQ_HOST_ROUTE"
 
 #: warn-once latches for unparseable env overrides (the watchdog/trace
@@ -1233,10 +1232,9 @@ def plan_table_scan(
     buffer (cap :func:`freq_buffer_entries`, default 2^25), the scan runs
     RESIDENT: per-row keys append at memcpy speed with NO compaction cond
     in the trace, and the single drain-time aggregation is exact for ANY
-    cardinality up to the buffer — the fast path the bench grouping stage
-    measures. On a mesh of ``shards`` devices (``batch_rows`` a multiple of
-    it) a resident buffer splits over them, one row of a ``1/shards`` share
-    per device. The cap holds for the rows taken together: the drain's
+    cardinality up to the buffer. On a mesh of ``shards`` devices
+    (``batch_rows`` a multiple of it) a resident buffer splits over them,
+    one row of a ``1/shards`` share per device. The cap holds for the rows taken together: the drain's
     exchange sends each device a block as long as a whole row from every
     device, so each device receives and sorts ``shards`` rows, which must
     fit what one device drains. A resident plan gets a minimal table:

@@ -306,8 +306,6 @@ SCAN_DEADLINE_ENV = "DEEQU_TPU_SCAN_DEADLINE_S"
 # - DEEQU_TPU_TRACE: span tracing. Default ON ("1"/unset); "0" disables
 #   entirely; a float in (0, 1) samples that fraction of root traces
 #   deterministically (unparseable values warn once and keep the default).
-#   Measured overhead of default-on tracing is <2% on the bench scan stage
-#   (PERF.md "Tracing overhead").
 # - DEEQU_TPU_TRACE_RING: capacity of the flight-recorder ring of recent
 #   finished spans (default 4096) — what /trace serves and what
 #   typed-failure post-mortem dumps snapshot.
@@ -449,11 +447,11 @@ from .service.catalog import (  # noqa: E402,F401
 #   nothing is ever promoted).
 # - DEEQU_TPU_TUNING_MIN_SAMPLES: measured folds each experiment arm
 #   needs before a promotion/demotion verdict (default 32; minimum 1).
-# - DEEQU_TPU_TUNING_BAND: the bench_diff-style tolerance band — a
-#   candidate promotes only when its measured rows/s beats the incumbent
-#   by MORE than this fraction, and the floor guardrail demotes tuned
-#   knobs when the live rate falls this far below the measured
-#   static-default rate (default 0.25, the bench_diff CI tolerance).
+# - DEEQU_TPU_TUNING_BAND: the tolerance band — a candidate promotes
+#   only when its measured rows/s beats the incumbent by MORE than this
+#   fraction, and the floor guardrail demotes tuned knobs when the live
+#   rate falls this far below the measured static-default rate
+#   (default 0.25).
 #
 # Every tunable routing constant (fast-path ceiling, coalesce width,
 # fleet sharding floor, prefetch depth, frequency-engine capacities, the

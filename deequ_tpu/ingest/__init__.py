@@ -1,8 +1,7 @@
 """High-throughput ingestion plane: socket to device.
 
-The engine scans orders of magnitude faster than the feed links that were
-serving it (ROADMAP item 4: the bench's feed probe read 6-30 MB/s against
-a ~GB/s device appetite). This package is the missing frontend:
+The engine scans faster than a naive feed link can serve it. This
+package is the frontend between the two:
 
 - :mod:`.columnar` — in-process coercion: dict-of-numpy / Arrow tables /
   record batches become :class:`~deequ_tpu.data.Dataset` with no pandas

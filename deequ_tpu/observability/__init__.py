@@ -7,8 +7,7 @@
   (``DEEQU_TPU_TRACE_RING``); typed failures dump their correlated trace
   snippet as JSONL post-mortem artifacts (``DEEQU_TPU_FLIGHT_DIR``).
 - `export` — Chrome trace-event / Perfetto JSON + JSONL journal, served
-  from the ``/trace`` endpoint on `service.MetricsExporter` and written
-  per-stage by ``bench.py``.
+  from the ``/trace`` endpoint on `service.MetricsExporter`.
 
 See README "Observability" for the span model and operator contract.
 """

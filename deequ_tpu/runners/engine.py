@@ -71,8 +71,7 @@ class RunMonitor:
     #: XLA program traces NEWLY paid during this monitor's runs (a DELTA,
     #: unlike ``jit_compiles`` which mirrors the absolute program-cache
     #: occupancy): a warm re-run of the same battery records 0 here. The
-    #: compile-budget regression test and the bench's per-stage artifact
-    #: key on this.
+    #: compile-budget regression test keys on this.
     program_compiles: int = 0
     placement: Optional[str] = None
     feed_bandwidth_mbps: Optional[float] = None
@@ -538,7 +537,7 @@ class PackedScanProgram:
 
     def __call__(self, carry, features: Dict[str, jax.Array]):
         """Dispatch one batch with a GLOBAL features dict (keys = this
-        program's own analyzers' spec keys — the monolithic/bench entry)."""
+        program's own analyzers' spec keys — the monolithic entry)."""
         slots = tuple(
             tuple(features[k] for k in keys) for keys in self._spec_keys
         )
@@ -1865,8 +1864,7 @@ class _DeviceFeatureCache:
 
 
 #: env var overriding the host ingest tier's partial-worker pool size
-#: (default: all cores). The `tools/host_tier_sweep.py` scaling sweep
-#: drives this; PERF.md records the measured workers -> rows/s curve.
+#: (default: all cores).
 HOST_TIER_WORKERS_ENV = "DEEQU_TPU_HOST_TIER_WORKERS"
 
 #: env var enabling the device feature cache; value = HBM budget in GB

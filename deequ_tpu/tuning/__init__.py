@@ -9,7 +9,7 @@ Three layers close the loop the hand-measured constants left open:
   profile beside the XLA cache;
 - :mod:`~deequ_tpu.tuning.controller` — the online re-fitter that
   shadow-routes candidates under live traffic and promotes only behind
-  a bench_diff-style band, with a never-below-static floor guardrail.
+  a tolerance band, with a never-below-static floor guardrail.
 
 ``DEEQU_TPU_AUTOTUNE=0`` disables all of it: no profile load, no
 controller, every knob read byte-identical to the static defaults.

@@ -5,22 +5,23 @@ measurement harness — the same host-partial kernels, the same warmed
 ``do_analysis_run`` device dispatch, the same grouping engines the
 CrossoverRouter and ``probably_low_cardinality`` route between — and
 runs each probe a few times, keeping the **minimum** wall time (the
-bench stages' convention: the min is the least-noisy estimate of the
-true cost on a busy box). From the raw probe measurements it derives
-values for every substrate-sensitive knob in the registry via the same
-cost model the router uses, clamps them to the registry bounds, and
-persists a checksummed :class:`~deequ_tpu.tuning.profile.SubstrateProfile`
-beside the XLA cache.
+min is the least-noisy estimate of the true cost on a busy box). From
+the raw probe measurements it derives values for every
+substrate-sensitive knob in the registry via the same cost model the
+router uses, clamps them to the registry bounds, and persists a
+checksummed :class:`~deequ_tpu.tuning.profile.SubstrateProfile` beside
+the XLA cache.
 
 Probe sizes are deliberately small (the default measures ~1.5M rows
-total): calibration runs once per substrate, at boot or from bench's
-``calibration`` stage, and must cost seconds — not the minutes a full
-sweep costs. The derived values are SEEDS with honest error bars, not
-gospel: the online controller refines them under live traffic, and the
-shadow-route guardrail catches any probe that mis-measured.
+total): calibration runs once per substrate, at boot or from the CLI
+below, and must cost seconds — not the minutes a full sweep costs. The
+derived values are SEEDS with honest error bars, not gospel: the online
+controller refines them under live traffic, and the shadow-route
+guardrail catches any probe that mis-measured.
 
 CLI: ``python -m deequ_tpu.tuning.calibrate --json [--no-save] [--dir D]
-[--rows N]`` — used by bench.py's detached calibration stage.
+[--rows N]`` — calibrates this substrate, saves the profile (unless
+``--no-save``) and prints it (as JSON with ``--json``).
 """
 
 from __future__ import annotations

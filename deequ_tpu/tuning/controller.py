@@ -1,5 +1,5 @@
 """The online tuning controller: shadow-route experiments with
-bench_diff-style promotion bands and a never-below-static floor.
+tolerance-band promotion and a never-below-static floor.
 
 The boot-time profile seeds the knobs; this controller refines them
 under LIVE traffic. It never flips a knob on a hunch: every change runs
@@ -16,9 +16,9 @@ as an :class:`Experiment` first —
   the comparison — reverted immediately if it regresses.
 
 A candidate **promotes** only when its measured rate beats the incumbent
-by more than the tolerance band (``DEEQU_TPU_TUNING_BAND``, the same
-default tolerance ``tools/bench_diff.py`` gates CI on) after both arms
-hold enough samples; anything less — including "inconclusive" — rejects.
+by more than the tolerance band (``DEEQU_TPU_TUNING_BAND``) after both
+arms hold enough samples; anything less — including "inconclusive" —
+rejects.
 Separately, a standing **floor guardrail** remembers the measured rate
 under static defaults and demotes any tuned knob whose live rate falls
 below that floor, so a mis-tuned controller (or a poisoned profile) can

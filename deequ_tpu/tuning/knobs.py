@@ -64,7 +64,7 @@ DEFAULT_TUNING_SHADOW_FRACTION = 0.05
 TUNING_MIN_SAMPLES_ENV = "DEEQU_TPU_TUNING_MIN_SAMPLES"
 DEFAULT_TUNING_MIN_SAMPLES = 32
 
-#: the bench_diff-style tolerance band: a candidate promotes only when
+#: the tolerance band: a candidate promotes only when
 #: its measured rate beats the incumbent by MORE than this fraction, and
 #: a promoted setting demotes back to static when it falls this far
 #: below the static reference rate

@@ -1,7 +1,5 @@
 """Tenant-catalog soak tool: the ISSUE 17 acceptance drills at tiny
-tier-1 scale (the CI-sized soak is `python -m tools.catalog_soak`; the
-bench's `catalog_soak` stage runs it detached and `bench_diff` gates the
-`gated_throughput_fraction` scalar)."""
+tier-1 scale (the full-size soak is `python -m tools.catalog_soak`)."""
 
 import pytest
 

@@ -13,9 +13,7 @@ Pins the ISSUE-2 contract end to end with the deterministic fault harness
 - OOM -> batch bisection; poisoned host batch -> isolation rerun absorbs
   it; host accumulator faults knock out only themselves;
 - the service's placement router learns device failures (probation) and
-  the scheduler harvests reliability signals from RunMonitor;
-- bench.py's per-stage hard deadline skips-and-records instead of letting
-  one stage starve the rest (VERDICT r5 weak #1).
+  the scheduler harvests reliability signals from RunMonitor.
 """
 
 import numpy as np
@@ -568,33 +566,3 @@ class TestRouterLearning:
                 with pytest.raises(JobFailed) as info:
                     handle.result(timeout=120)
             assert isinstance(info.value.__cause__, WorkerCrash)
-
-
-class TestBenchStageBudget:
-    def test_deadline_skips_and_records(self, monkeypatch):
-        import time as time_mod
-
-        import bench
-
-        monkeypatch.setenv(bench.STAGE_BUDGET_ENV, "1")
-
-        def over_budget():
-            time_mod.sleep(5)
-            return {"never": True}
-
-        result, status, seconds = bench.run_stage_with_deadline(
-            "slow_stage", over_budget
-        )
-        assert result is None
-        assert status == "skipped_deadline"
-        assert seconds < 3
-
-    def test_within_budget_passes_through(self, monkeypatch):
-        import bench
-
-        monkeypatch.setenv(bench.STAGE_BUDGET_ENV, "30")
-        result, status, _ = bench.run_stage_with_deadline(
-            "fast_stage", lambda: {"value": 7}
-        )
-        assert result == {"value": 7}
-        assert status == "ok"

@@ -23,13 +23,11 @@ The ISSUE 17 acceptance drills, one invocation, one JSON line:
 4. **Gated vs ungated throughput**: the same Arrow stream is folded
    through a session WITH a row gate (all rows conforming — the
    production steady state) and one WITHOUT; reports
-   ``gated_throughput_fraction`` (gated MB/s / ungated MB/s — the
-   bench_diff-gated scalar; acceptance floor 0.8) and pins the two
-   sessions' cumulative metrics BIT-EXACT.
+   ``gated_throughput_fraction`` (gated MB/s / ungated MB/s; acceptance
+   floor 0.8) and pins the two sessions' cumulative metrics BIT-EXACT.
 
-Exit code 0 iff every verdict holds, 1 on a failed verdict. ``--stage-
-json`` is accepted for bench-stage symmetry (the JSON line is always
-printed).
+Exit code 0 iff every verdict holds, 1 on a failed verdict. The JSON
+summary is always printed.
 
 Usage::
 
@@ -283,8 +281,6 @@ def main(argv=None) -> int:
                         help="acceptance floor for gated/ungated MB/s "
                              "(0 disables; timing floors are meaningless "
                              "at toy sizes)")
-    parser.add_argument("--stage-json", action="store_true",
-                        help="bench-stage symmetry; JSON always prints")
     args = parser.parse_args(argv)
 
     t0 = time.perf_counter()

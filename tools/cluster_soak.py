@@ -40,8 +40,8 @@ on a CROSS-PROCESS trace: at least one trace_id whose front-side
 journals. It also fetches a worker's ``/statusz``, schema-validates it,
 and requires all six ops planes present.
 
-``--stage-json`` is accepted for bench-stage symmetry (the JSON line is
-always printed). The worker side (``--worker I --dir D``) is internal.
+The JSON line is always printed. The worker side (``--worker I --dir D``)
+is internal.
 """
 
 from __future__ import annotations
@@ -678,8 +678,6 @@ def main() -> int:
     parser.add_argument("--batches", type=int, default=DEFAULT_BATCHES)
     parser.add_argument("--rows", type=int, default=DEFAULT_ROWS)
     parser.add_argument("--drill", choices=["kill-one"], default=None)
-    parser.add_argument("--stage-json", action="store_true",
-                        help="bench-stage symmetry flag (JSON always prints)")
     args = parser.parse_args()
 
     if args.worker is not None:

@@ -15,8 +15,7 @@ concurrent sessions under bounded admission", "saturate the feed link"):
    Arrow IPC payloads through `deequ_tpu.ingest.fold_stream` — the same
    decode + atomic-fold path the HTTP endpoint runs — at production batch
    shapes. Reports sustained MB/s and rows/s including decode, checksum
-   (optional) and the full verification fold, versus the raw feed-link
-   probe the bench reports.
+   (optional) and the full verification fold.
 
 Usage::
 
@@ -173,8 +172,7 @@ def run_concurrency_soak(
             "ok": failed == 0 and done_sessions == sessions,
         })
         # tail latency from the service's own SLO histograms (merged
-        # across tenants): what fleetwatch burn rates are computed from,
-        # surfaced here so bench_diff can regress on it
+        # across tenants): what fleetwatch burn rates are computed from
         from deequ_tpu.service.metrics import histogram_quantile
 
         for slug, series in (

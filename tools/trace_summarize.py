@@ -1,11 +1,10 @@
 """Summarize a trace artifact: critical path, top self-time spans,
 degradation events.
 
-Reads either a Chrome trace-event JSON (the ``/trace`` endpoint /
-``bench.py`` per-stage artifacts) or a span JSONL journal (flight-recorder
-dumps, ``/trace.jsonl``), rebuilds the span tree from the embedded
-``span_id``/``parent_id`` refs, and prints the three things a post-mortem
-opens with:
+Reads either a Chrome trace-event JSON (the ``/trace`` endpoint) or a
+span JSONL journal (flight-recorder dumps, ``/trace.jsonl``), rebuilds
+the span tree from the embedded ``span_id``/``parent_id`` refs, and
+prints the three things a post-mortem opens with:
 
 1. **Critical path** — from the longest root, the chain of child spans
    that dominates wall time (the "why was this run slow" answer);

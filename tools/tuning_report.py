@@ -76,85 +76,6 @@ def controller_report(service) -> Dict[str, Any]:
     return out
 
 
-def bench_point(sessions: int = 96, rows: int = 4096,
-                group_rows: int = 1 << 19,
-                group_cardinality: int = 1 << 10) -> Dict[str, Any]:
-    """One tuned-vs-static comparison point under the CURRENT env:
-    streaming sessions/s (the knee workload's shape: N sessions x one
-    micro-batch) and grouping rows/s (a warm Uniqueness run). bench.py's
-    calibration stage runs this twice in detached subprocesses —
-    DEEQU_TPU_AUTOTUNE=0 vs the calibrated profile — and bench_diff
-    gates tuned >= static within the band."""
-    import os
-    import time
-
-    import numpy as np
-    import pyarrow as pa
-
-    from deequ_tpu.analyzers import Uniqueness
-    from deequ_tpu.checks import Check, CheckLevel
-    from deequ_tpu.data import Dataset
-    from deequ_tpu.runners.analysis_runner import AnalysisRunner
-    from deequ_tpu.service import VerificationService
-    from deequ_tpu.tuning import knobs
-
-    rng = np.random.default_rng(0xBE9C4)
-    checks = [
-        Check(CheckLevel.ERROR, "tuning point")
-        .is_complete("x")
-        .has_mean("y", lambda m: -5.0 < m < 5.0)
-    ]
-    table = pa.table({
-        "x": rng.normal(size=rows),
-        "y": rng.normal(size=rows),
-    })
-    with VerificationService(background_warm=False) as service:
-        warm = service.session("tuning-point-warm", "stream", checks)
-        # Warm BOTH fold routes before timing (forced via the override
-        # knob, saved/restored): the arms may settle on different routes
-        # — the calibrated router flips to device as soon as the host
-        # EWMA absorbs its first-fold setup cost, the static 20ms fixed
-        # seed never does — and whichever route the timed loop takes
-        # must not pay its one-time program compile inside the window.
-        route_env = "DEEQU_TPU_FAST_PATH_MAX_ROWS"
-        saved = os.environ.get(route_env)
-        try:
-            os.environ[route_env] = "0"  # force the device route
-            warm.ingest(table, timeout=120)
-            os.environ[route_env] = str(1 << 30)  # force the host route
-            warm.ingest(table, timeout=120)
-        finally:
-            if saved is None:
-                os.environ.pop(route_env, None)
-            else:
-                os.environ[route_env] = saved
-        warm.ingest(table, timeout=120)  # settle the model's own route
-        t0 = time.perf_counter()
-        for i in range(sessions):
-            s = service.session(f"tuning-point-{i}", "stream", checks)
-            s.ingest(table, timeout=120)
-        streaming = sessions / (time.perf_counter() - t0)
-
-    gdata = Dataset.from_dict({
-        "k": rng.integers(0, group_cardinality, size=group_rows),
-    })
-    analyzers = [Uniqueness(["k"])]
-    AnalysisRunner.do_analysis_run(gdata, analyzers)  # warm
-    t0 = time.perf_counter()
-    AnalysisRunner.do_analysis_run(gdata, analyzers)
-    grouping = group_rows / (time.perf_counter() - t0)
-
-    return {
-        "sessions": sessions,
-        "rows": rows,
-        "group_rows": group_rows,
-        "sessions_per_s": streaming,
-        "grouping_rows_per_s": grouping,
-        "autotune": knobs.autotune_enabled(),
-        "tuned_knobs": sorted(knobs.tuned_snapshot()),
-    }
-
-
 def _fmt(value: Any) -> str:
     if isinstance(value, float):
         return f"{value:.4g}"
@@ -208,21 +129,7 @@ def main(argv=None) -> int:
     parser.add_argument("--snapshot", default=None,
                         help="also render a controller snapshot JSON file "
                              "(as written by chaos_soak)")
-    parser.add_argument("--bench-point", action="store_true",
-                        help="measure one streaming+grouping throughput "
-                             "point under the current env and print it as "
-                             "a JSON line (bench.py's tuned-vs-static probe)")
-    parser.add_argument("--sessions", type=int, default=96,
-                        help="streaming sessions for --bench-point")
-    parser.add_argument("--group-rows", type=int, default=1 << 19,
-                        help="grouping rows for --bench-point")
     args = parser.parse_args(argv)
-
-    if args.bench_point:
-        point = bench_point(sessions=args.sessions,
-                            group_rows=args.group_rows)
-        print(json.dumps(point, sort_keys=True))
-        return 0
 
     report = profile_report(args.dir)
     if args.snapshot:
